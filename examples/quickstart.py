@@ -10,12 +10,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax  # noqa: E402
 
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import registry  # noqa: E402
 from repro.train.trainer import Trainer, TrainerConfig  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True)
     t = Trainer(bundle, mesh, TrainerConfig(
         global_batch=8, seq_len=64, ckpt_dir="/tmp/repro_quickstart",

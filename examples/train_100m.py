@@ -13,6 +13,7 @@ import dataclasses  # noqa: E402
 import jax  # noqa: E402
 
 from repro.configs.llama3_8b import CONFIG  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import registry  # noqa: E402
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.train.trainer import Trainer, TrainerConfig  # noqa: E402
@@ -30,7 +31,7 @@ def main():
         n_kv_heads=4, d_ff=2048, vocab_size=32000,
         param_dtype="float32", dtype="float32")
     bundle = registry.bundle_for(cfg)
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     t = Trainer(bundle, mesh,
                 TrainerConfig(global_batch=args.global_batch,
                               seq_len=args.seq,

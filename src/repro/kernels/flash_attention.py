@@ -92,7 +92,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B,Sq,H,hd); k/v: (B,Sk,Hk,hd) -> (B,Sq,H,hd)."""
     B, Sq, H, hd = q.shape
     Sk, Hk = k.shape[1], k.shape[2]

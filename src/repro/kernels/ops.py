@@ -1,15 +1,8 @@
-"""jit'd dispatch wrappers: Pallas kernel on TPU, jnp oracle elsewhere.
-
-``backend()`` resolves once per call site:
-  * "pallas"     — compiled Pallas (real TPU)
-  * "interpret"  — Pallas interpret=True (CPU correctness, slow)
-  * "ref"        — pure-jnp oracle (default on CPU; XLA fuses it)
-Set REPRO_KERNELS=pallas|interpret|ref to force.
-"""
+"""jit'd dispatch wrappers: compiled Pallas kernel on TPU, the pure-jnp
+oracle (which XLA fuses) on every other backend."""
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -18,45 +11,37 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import ref, rmsnorm as rn, ssm_scan as ss, swiglu as sg
 
 
-def backend() -> str:
-    forced = os.environ.get("REPRO_KERNELS")
-    if forced:
-        return forced
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+def _pallas() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap"))
 def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None):
-    be = backend()
-    if be == "ref":
+    if not _pallas():
         return ref.flash_attention_ref(q, k, v, causal=causal,
                                        window=window, softcap=softcap)
     return fa.flash_attention(q, k, v, causal=causal, window=window,
-                              softcap=softcap,
-                              interpret=(be == "interpret"))
+                              softcap=softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def rmsnorm(x, scale, eps: float = 1e-5):
-    be = backend()
-    if be == "ref":
+    if not _pallas():
         return ref.rmsnorm_ref(x, scale, eps)
-    return rn.rmsnorm(x, scale, eps, interpret=(be == "interpret"))
+    return rn.rmsnorm(x, scale, eps)
 
 
 @jax.jit
 def ssm_scan(u, dt, Bc, Cc, A):
-    be = backend()
-    if be == "ref":
+    if not _pallas():
         return ref.ssm_scan_ref(u, dt, Bc, Cc, A)
-    return ss.ssm_scan(u, dt, Bc, Cc, A, interpret=(be == "interpret"))
+    return ss.ssm_scan(u, dt, Bc, Cc, A)
 
 
 @jax.jit
 def swiglu(g, u):
-    be = backend()
-    if be == "ref":
+    if not _pallas():
         return ref.swiglu_ref(g, u)
-    return sg.swiglu(g, u, interpret=(be == "interpret"))
+    return sg.swiglu(g, u)

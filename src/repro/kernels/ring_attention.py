@@ -169,7 +169,7 @@ def _step_kernel(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
 def ring_step(q, k, v, m, l, acc, *, q_start: int, k_start: int,
               k_valid: int, causal: bool = True,
               block_q: int = 128, block_k: int = 128,
-              interpret: bool = True):
+              interpret: bool = False):
     """One ring hop as a Pallas kernel: fold the visiting (padded) KV
     block into the carried ``(m, l, acc)`` online-softmax state.
 
@@ -253,7 +253,7 @@ def ring_step(q, k, v, m, l, acc, *, q_start: int, k_start: int,
 def ring_flash_attention(q, k, v, cp_chunks: Sequence[int], *,
                          causal: bool = True, use_pallas: bool = False,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool = False) -> jax.Array:
     """Full ring attention on one host, in the distributed ring's exact
     accumulation order — the math contract for the cp loss builder.
 

@@ -17,7 +17,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x, scale, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """x: (..., D); scale: (D,)."""
     shape = x.shape
     D = shape[-1]

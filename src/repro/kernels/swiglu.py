@@ -6,6 +6,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# bytes of one (rows, F) block: g, u and out are double-buffered and the
+# body holds two fp32 temporaries, ~10 blocks in all, under the 16 MiB
+# default scoped VMEM
+BLOCK_BYTES = 1 << 20
+
 
 def _kernel(g_ref, u_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)
@@ -13,12 +18,23 @@ def _kernel(g_ref, u_ref, o_ref):
                   * u_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def swiglu(g, u, block_rows: int = 256, interpret: bool = True) -> jax.Array:
+def _rows_for(F: int, itemsize: int, block_rows: int) -> int:
+    """Largest power of two <= block_rows whose (rows, F) block fits
+    BLOCK_BYTES, and at least 16 (one packed bf16 sublane tile)."""
+    fit = max(BLOCK_BYTES // (F * itemsize), 16)
+    br = 16
+    while br * 2 <= min(fit, block_rows):
+        br *= 2
+    return min(br, block_rows)
+
+
+def swiglu(g, u, block_rows: int = 256, interpret: bool = False
+           ) -> jax.Array:
     shape = g.shape
     F = shape[-1]
     gf, uf = g.reshape(-1, F), u.reshape(-1, F)
     R = gf.shape[0]
-    br = min(block_rows, R)
+    br = min(_rows_for(F, g.dtype.itemsize, block_rows), R)
     pad = (-R) % br
     if pad:
         z = jnp.zeros((pad, F), gf.dtype)

@@ -21,7 +21,6 @@ from repro.models.config import ModelConfig
 from repro.parallel import pipeline
 from repro.parallel.sharding import ShardingRules
 from repro.train import steps
-from repro.utils import compat
 
 TP = 16
 PP_MULTIPOD = 2
@@ -98,7 +97,7 @@ class Cell:
                          in_shardings=jax.tree.map(ns, self.in_shardings),
                          out_shardings=jax.tree.map(ns, self.out_shardings),
                          donate_argnums=self.meta.get("donate", ()))
-        with compat.set_mesh(mesh):  # activation constraints need mesh context
+        with jax.set_mesh(mesh):  # activation constraints need mesh context
             return jitted.lower(*self.args_sds)
 
 

@@ -1,7 +1,10 @@
 import os
+# A CPU tool: 512 host devices stand in for the pods, and JAX_PLATFORMS=cpu
+# keeps this process and the per-cell children it spawns off any TPU.
+# MUST precede any jax import: jax locks platform and device count on init.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# ^ MUST precede any jax import: jax locks the device count on first init.
 
 """Multi-pod dry-run driver (deliverable e).
 
@@ -27,6 +30,7 @@ import jax
 
 from repro.configs.shapes import SHAPES
 from repro.launch import cells as cells_mod
+from repro.launch import compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.utils import hlo as hlo_util
@@ -71,15 +75,6 @@ def record_profile(rec, cell, mesh_kind: str, n_chips: int):
     store.save()
 
 
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on modern jax, a one-element
-    list of dicts on 0.4.x — normalize."""
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return c
-
-
 def model_flops_total(cfg, shape) -> float:
     """6*N*D yardstick: fwd+bwd for train (3x fwd), fwd for serving."""
     if shape.step == "train":
@@ -111,7 +106,7 @@ def _probe_costs(arch, shape_name, mesh, n_layers_probe, strategy="tp",
     cell = cells_mod.build_cell(arch, shape_name, False,
                                 extra_overrides=ov, strategy=strategy)
     compiled = cell.lower(mesh).compile()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     stats = hlo_util.collective_stats(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)),
@@ -155,7 +150,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mesh, verbose=True,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo_text = compiled.as_text()
         # scan trip count: collectives inside while bodies replay per layer
         # (hybrid stacks scan over full pattern cycles)
@@ -254,6 +249,7 @@ def main():
     ap.add_argument("--grad-accum", type=int, default=1,
                     help="microbatch the train step (activation memory)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     def parse_overrides():
         out = {}

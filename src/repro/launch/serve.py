@@ -28,6 +28,7 @@ import jax
 from repro.core import planner
 from repro.core.cluster import ClusterSpec, DeviceType, NodeGroup
 from repro.core.plan import ServingSLO, TrafficProfile
+from repro.launch import compile_cache
 from repro.models import registry
 from repro.obs.metrics import MetricsLog
 from repro.obs.runmeta import RunMeta, plan_digest
@@ -74,6 +75,7 @@ def main():
     ap.add_argument("--request-rate", type=float, default=4.0)
     ap.add_argument("--drift-threshold", type=float, default=1.5)
     args = ap.parse_args()
+    compile_cache.enable()
 
     b = registry.get_bundle(args.arch, smoke=args.smoke)
     cfg = b.cfg

@@ -38,6 +38,8 @@ import time
 
 import jax
 
+from repro.launch import compile_cache
+from repro.launch.mesh import make_train_mesh
 from repro.models import registry
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -158,6 +160,7 @@ def main():
                          "<ckpt-dir>/flight.json when any observability "
                          "output is enabled)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.arch == "llama-100m":
         import dataclasses
@@ -175,7 +178,6 @@ def main():
                                      **overrides)
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
     cluster = plan = store = None
     # ONE search space for the initial plan, the manual degrade replan,
     # and the controller's autonomous replans — diverging constraints
@@ -197,6 +199,8 @@ def main():
         # the telemetry folds land here, so the degrade replan below
         # searches against observed (scaled) costs once dense enough
         store = ProfileStore()
+    # a pipelined plan gets its pod axis: each stage's blocks on its chips
+    mesh = make_train_mesh(plan)
     degrade_kind, degrade_factor, degrade_step = None, 1.0, None
     if args.degrade is not None:
         degrade_kind, degrade_factor, degrade_step = args.degrade

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.utils import compat
 from repro.models.config import ModelConfig
 from repro.models.layers import _he
 
@@ -224,7 +223,7 @@ def moe_mlp_manual(p, x, cfg: ModelConfig):
     else:
         w_specs = (P(None, None, tpax), P(None, None, tpax),
                    P(None, tpax, None))
-    return compat.shard_map(
+    return jax.shard_map(
         body, in_specs=(P(dp, tpax, None), P()) + w_specs,
         out_specs=(P(dp, tpax, None), P()),
         check_vma=False,

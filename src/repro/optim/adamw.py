@@ -34,8 +34,13 @@ def init_opt_state(params: Any, keep_master: bool = True) -> dict:
         "count": jnp.zeros((), jnp.int32),
     }
     if keep_master:
+        # the master starts at the params' own bf16 values.  Without the
+        # barrier, XLA on TPU fuses a jitted init and hands the master the
+        # unrounded f32 draw (excess precision), so two programs that
+        # place the same init differently start from different masters.
         state["master"] = jax.tree.map(
-            lambda p: p.astype(jnp.float32), params)
+            lambda p: p.astype(jnp.float32),
+            jax.lax.optimization_barrier(params))
     return state
 
 

@@ -41,11 +41,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.iccl.communicator import Communicator
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.parallel.sharding import ShardingRules
 from repro.profile.store import ProfileStore
 from repro.train import steps
-from repro.utils import compat
 
 
 # ----------------------------------------------------------------- timing --
@@ -159,12 +159,12 @@ def bench_collectives(store: ProfileStore, dev: str,
         if verbose:
             print("  collectives: single device — skipped")
         return
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator(axis="x")
 
     def shard_fn(body):
-        return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P("x"),),
-                                        out_specs=P("x"), check_vma=False))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
+                                     out_specs=P("x"), check_vma=False))
 
     perm = [(i, (i + 1) % n) for i in range(n)]
     cases = {

@@ -53,7 +53,6 @@ from repro.parallel import context, pipeline
 from repro.parallel.sharding import ShardingRules
 from repro.telemetry import StageTelemetry
 from repro.train import steps as steps_mod
-from repro.utils import compat
 
 
 @dataclasses.dataclass
@@ -80,8 +79,9 @@ class TrainerConfig:
     # chatty for the fabric.
     aggregate_every: int = 1
     # stage telemetry mode for the pipeline step: "auto" picks per-tick
-    # host callbacks on CPU backends and cheap step-bucketed timers
-    # elsewhere; "off" disables recording entirely
+    # host callbacks on a single-device CPU mesh (ordered callbacks run on
+    # one device only) and cheap step-bucketed timers elsewhere; "off"
+    # disables recording entirely
     telemetry: str = "auto"
     # bounded staleness for profile entries of DEPARTED device kinds: a
     # lost island's measurements are kept this many steps (a flapping
@@ -207,7 +207,7 @@ class Trainer:
             mode = self.cfg.telemetry
             if mode == "auto":
                 mode = ("callback" if jax.default_backend() == "cpu"
-                        else "timer")
+                        and self.mesh.size == 1 else "timer")
             self.telemetry = (StageTelemetry(plan.pp, plan.vpp, m, mode=mode)
                               if mode != "off" else None)
             if self.obs is not None and self.telemetry is not None:
@@ -237,7 +237,13 @@ class Trainer:
             self.telemetry = None
             self.train_step = steps_mod.make_train_step(
                 self.bundle, self.rules, self.opt_cfg)
-        self._jit = jax.jit(self.train_step, donate_argnums=0)
+        # the state keeps its layout across steps: left to propagation,
+        # the ZeRO-1 moments' data sharding spreads onto the params, and
+        # the second step recompiles for the new input layout
+        self._jit = jax.jit(
+            self.train_step, donate_argnums=0,
+            out_shardings=(self._state_shardings(self._state_sds()),
+                           NamedSharding(self.mesh, P())))
         if self.obs is not None and self._pipeline_active() \
                 and self.cluster is not None:
             # a (re)build IS a plan adoption: render a fresh predicted
@@ -294,7 +300,7 @@ class Trainer:
         state_sds = self._state_sds(layout)
         shardings = self._state_shardings(state_sds)
         if step is None:
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state = jax.jit(
                     lambda k: self._init_state(k, layout),
                     out_shardings=shardings)(key)
@@ -356,7 +362,7 @@ class Trainer:
             t0 = time.perf_counter()
             np_batch = self.data.batch_at(self.step)
             batch = self._device_batch(np_batch)
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state, metrics = self._jit(self.state, batch)
             jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0

@@ -36,6 +36,7 @@ from repro.adapt import (AdaptConfig, InMemoryFanIn, LocalAggregator,
 from repro.core import cluster as C
 from repro.core import planner
 from repro.core.plan import ParallelPlan, StagePlacement
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.profile.model import BUCKETED_WEIGHT, ProfiledCostModel
 from repro.profile.store import ProfileStore
@@ -345,7 +346,7 @@ ADAPT_SEARCH_KW = {k: v for k, v in SEARCH_KW.items()
 
 
 def _mk_trainer(tmp, policy=None, aggregator=None):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     cl = _two_island_cluster()
     plan = ParallelPlan(stages=(StagePlacement(0, 3, 1, 1, False),
@@ -486,7 +487,7 @@ def test_e2e_cp_ring_link_degrade_triggers_replan_schedule(tmp_path):
     policy must fire ``replan-schedule`` (no straggler blamed) and the
     re-search must sweep ``cp_options`` on the UNCHANGED cluster."""
     from repro.core import segmentation
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     # two accelerators per island so every stage has dp=2 (cp=2 | dp)
     cl = C.ClusterSpec(groups=(C.NodeGroup(C.AMD, 1, accel_per_node=2),
@@ -679,7 +680,7 @@ def test_trainer_cost_source_reads_aggregated_view(tmp_path):
         remote.fold("cpu", "observed_layer_step",
                     {"arch": bundle.cfg.name, "seq_len": 32, "tp": 1},
                     "per_seq_s", 0.01)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cl = _two_island_cluster()
     t = Trainer(bundle, mesh,
                 TrainerConfig(global_batch=8, seq_len=32,

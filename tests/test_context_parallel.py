@@ -33,6 +33,7 @@ from repro.core.plan import ParallelPlan, StagePlacement
 from repro.core.predictor import PerformancePredictor
 from repro.kernels import ref
 from repro.kernels import ring_attention as ra
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.parallel import context
 from repro.parallel.sharding import ShardingRules
@@ -150,7 +151,8 @@ def test_ring_pallas_path_matches_reference():
     k = jax.random.normal(ks[1], (1, S, 2, 32))
     v = jax.random.normal(ks[2], (1, S, 2, 32))
     out = ra.ring_flash_attention(q, k, v, chunks, causal=True,
-                                  use_pallas=True, block_q=32, block_k=32)
+                                  use_pallas=True, block_q=32, block_k=32,
+                                  interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -381,7 +383,7 @@ def test_cp_loss_rejects_unsupported(_bundle):
 def test_trainer_runs_cp_plan(_bundle):
     """A pp=1 cp>1 plan routes through the cp loss builder and the losses
     track a reference (no-plan) trainer step for step."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cl = C.homogeneous_cluster(C.GPU_A, 2)
 
     def mk(plan):
@@ -406,7 +408,7 @@ def test_trainer_runs_cp_plan(_bundle):
 
 def test_trainer_cp1_plan_keeps_reference_step(_bundle):
     """cp=1 never enters the cp builder — the default train step runs."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cl = C.homogeneous_cluster(C.GPU_A, 2)
     plan = ParallelPlan(stages=(StagePlacement(0, 4, 2, 1, True),),
                         micro_bs=8, global_batch=8, seq_len=32)

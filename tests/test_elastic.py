@@ -35,6 +35,7 @@ from repro.ckpt.checkpoint import _norm_layout
 from repro.core import cluster as C
 from repro.core import planner
 from repro.core.plan import ParallelPlan, StagePlacement
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.profile.store import ProfileStore
 from repro.train.trainer import Trainer, TrainerConfig
@@ -243,7 +244,7 @@ def _bit_exact(a, b):
 
 
 def _mk_elastic(tmp, cl, plan=None, aggregator=None, **kw):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     if plan is None:
         plan = planner.search(cl, bundle.cfg, global_batch=8, seq_len=32,
@@ -417,7 +418,7 @@ def test_e2e_stale_profile_expires_after_window(tmp_path):
     ``profile_stale_steps`` the planner no longer sees the departed
     kind."""
     cl = _two_island(accel=2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     plan = planner.search(cl, bundle.cfg, global_batch=8, seq_len=32,
                           **SEARCH_KW).plan

@@ -31,7 +31,6 @@ import signal
 import tempfile
 from pathlib import Path
 
-import jax
 import numpy as np
 import pytest
 
@@ -41,6 +40,7 @@ from repro.core import cluster as C
 from repro.core import fastsim, simulator
 from repro.core.plan import ParallelPlan, StagePlacement
 from repro.iccl import communicator
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import (FlightRecorder, MetricsLog, Observability, RunMeta,
                        TraceBuilder, install_sigterm, plan_digest,
@@ -396,7 +396,7 @@ def obs_e2e():
     valid, attributable, and bit-exact against the trainer's own
     numbers."""
     tmp = Path(tempfile.mkdtemp())
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     plan = _plan()
     obs = Observability(

@@ -11,10 +11,10 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.launch.mesh import make_mesh
 from repro.models import moe, registry
 from repro.models.config import ModelConfig
 from repro.parallel import pipeline
-from repro.utils import compat
 from repro.parallel.sharding import ShardingRules
 from repro.train import steps
 
@@ -152,16 +152,16 @@ import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.models import registry
+from repro.launch.mesh import make_mesh
 from repro.parallel import pipeline
 from repro.parallel.sharding import ShardingRules
 from repro.train import steps
 from repro.optim import adamw
-from repro.utils import compat
 b = registry.get_bundle("llama3-8b", smoke=True, num_layers=4,
                         param_dtype="bfloat16", dtype="bfloat16",
                         act_sharding=(("data",), "model", None))
 cfg = b.cfg
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = ShardingRules(cfg, tp=2, dp_axes=("data",))
 def init_state(k):
     p = pipeline.stack_blocks_for_stages(b.init(k, cfg), 2)
@@ -179,7 +179,7 @@ b_specs = {k: P(None, ("data",)) for k in bsd}
 lf = pipeline.make_pp_loss_fn(cfg, mesh, 2, 4)
 step = steps.make_train_step(b, rules, loss_fn=lf)
 ns = lambda s: NamedSharding(mesh, s)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(step, in_shardings=jax.tree.map(ns, (st_specs, b_specs)),
                 out_shardings=jax.tree.map(ns, (st_specs, {k: P() for k in
                 ("ce","aux","loss","grad_norm","lr")}))).lower(sds, bsd).compile()
@@ -300,8 +300,8 @@ def test_moe_manual_shard_map_matches_gspmd():
     p = moe.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
     ref, _ = moe._moe_mlp_gspmd(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(lambda p, x: moe.moe_mlp(p, x, cfg_m))(p, x)
         g_ref = jax.grad(
             lambda p: jnp.sum(moe._moe_mlp_gspmd(p, x, cfg)[0] ** 2))(p)
@@ -322,8 +322,8 @@ def test_moe_ep_matches_gspmd():
     p = moe.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
     ref, _ = moe._moe_mlp_gspmd(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(lambda p, x: moe.moe_mlp(p, x, cfg_ep))(p, x)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                rtol=1e-4, atol=1e-5)

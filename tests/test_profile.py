@@ -5,7 +5,6 @@ import json
 import tempfile
 from pathlib import Path
 
-import jax
 import pytest
 
 from repro.configs.llama2_paper import LLAMA2_70B
@@ -13,6 +12,7 @@ from repro.core import cluster as C
 from repro.core import costmodel, planner, segmentation
 from repro.core.plan import ParallelPlan, StagePlacement
 from repro.core.predictor import PerformancePredictor
+from repro.launch.mesh import make_mesh
 from repro.profile.model import CALIB_DEVICE, ProfiledCostModel
 from repro.profile.store import ProfileStore
 
@@ -243,7 +243,7 @@ def test_planner_with_profiled_source():
 def test_trainer_folds_observed_steps(tmp_path):
     from repro.models import registry
     from repro.train.trainer import Trainer, TrainerConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     b = registry.get_bundle("llama3-8b", smoke=True)
     store = ProfileStore(tmp_path / "online.json")
     t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,
@@ -268,7 +268,7 @@ def test_replan_uses_profiled_cost_source(tmp_path, monkeypatch):
     from repro.profile.runner import device_kind
     from repro.train import trainer as trainer_mod
     from repro.train.trainer import Trainer, TrainerConfig
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     b = registry.get_bundle("llama3-8b", smoke=True)
     store = ProfileStore(tmp_path / "online.json")
     t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,

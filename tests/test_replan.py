@@ -29,6 +29,7 @@ from repro.core import cluster as C
 from repro.core import planner
 from repro.core.plan import ParallelPlan, StagePlacement
 from repro.core.predictor import PerformancePredictor
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.profile.model import ProfiledCostModel
 from repro.profile.store import ProfileStore
@@ -479,7 +480,7 @@ def e2e():
     """Shared scenario: pipeline trainer on a CPU mesh with telemetry ->
     degrade -> replan (migrate in memory) -> checkpoint round-trip."""
     tmp = Path(tempfile.mkdtemp())
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bundle = registry.get_bundle("llama3-8b", smoke=True, num_layers=6)
     cl = C.ClusterSpec(groups=(C.NodeGroup(C.AMD, 1, accel_per_node=1),
                                C.NodeGroup(C.GPU_A, 1, accel_per_node=1)))
@@ -575,14 +576,13 @@ def test_e2e_loss_and_grads_match_bit_exact(e2e):
     produces identical loss AND identical updated parameters (grads are
     applied by the step, so equal next-params == equal grads)."""
     t = e2e["trainer"]
-    from repro.utils import compat
     step_fn = jax.jit(t.train_step)      # fresh jit, no donation
     shardings = t._state_shardings(jax.eval_shape(lambda: e2e["migrated"]))
     batch = t._device_batch(t.data.batch_at(t.step))
     outs = []
     for state in (e2e["migrated"], e2e["restarted"]):
         placed = t._place(state, shardings)
-        with compat.set_mesh(t.mesh):
+        with jax.set_mesh(t.mesh):
             new_state, metrics = step_fn(placed, batch)
         outs.append((jax.device_get(new_state),
                      float(jax.device_get(metrics["loss"]))))

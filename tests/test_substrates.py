@@ -12,7 +12,7 @@ from repro.ckpt import checkpoint as ckpt
 from repro.data.pipeline import DataState, SyntheticTokens
 from repro.iccl import transports
 from repro.iccl.communicator import Communicator
-from repro.utils import compat
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.train import steps
 from repro.train.trainer import Trainer, TrainerConfig
@@ -90,7 +90,7 @@ def test_checkpoint_shape_mismatch_raises():
 
 # ---------------------------------------------------------------- trainer --
 def test_trainer_loss_decreases_and_resumes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     b = registry.get_bundle("llama3-8b", smoke=True)
     with tempfile.TemporaryDirectory() as d:
         t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,
@@ -108,7 +108,7 @@ def test_trainer_loss_decreases_and_resumes():
 
 def test_trainer_elastic_replan():
     from repro.core import cluster as C
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     b = registry.get_bundle("llama3-8b", smoke=True)
     with tempfile.TemporaryDirectory() as d:
         t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,
@@ -127,7 +127,7 @@ def test_trainer_elastic_replan():
 
 
 def test_trainer_straggler_hook_fires():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     b = registry.get_bundle("llama3-8b", smoke=True)
     with tempfile.TemporaryDirectory() as d:
         t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,
@@ -141,7 +141,7 @@ def test_trainer_straggler_hook_fires():
 
 # ------------------------------------------------------------------ iccl ---
 def test_iccl_collectives_single_axis():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     comm = Communicator(axis="x")
 
     def f(v):
@@ -149,24 +149,24 @@ def test_iccl_collectives_single_axis():
                 comm.ireducescatter(v), comm.index())
 
     v = jnp.arange(4.0)
-    out = compat.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("x"),),
-                        out_specs=(jax.sharding.PartitionSpec("x"),) * 3
-                        + (jax.sharding.PartitionSpec(),),
-                        check_vma=False)(v)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("x"),),
+                     out_specs=(jax.sharding.PartitionSpec("x"),) * 3
+                     + (jax.sharding.PartitionSpec(),),
+                     check_vma=False)(v)
     np.testing.assert_array_equal(out[0], v)    # psum over size-1 axis = id
 
 
 def test_iccl_compression_roundtrip():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     comm = Communicator(axis="x", compress=True)
     v = jnp.float32(1.0) + jnp.arange(8, dtype=jnp.float32) * 1e-3
 
     def f(x):
         return comm.iallreduce(x)
 
-    out = compat.shard_map(f, mesh=mesh,
-                        in_specs=(jax.sharding.PartitionSpec(),),
-                        out_specs=jax.sharding.PartitionSpec())(v)
+    out = jax.shard_map(f, mesh=mesh,
+                     in_specs=(jax.sharding.PartitionSpec(),),
+                     out_specs=jax.sharding.PartitionSpec())(v)
     assert out.dtype == jnp.float32
     np.testing.assert_allclose(out, v, rtol=1e-2)
 

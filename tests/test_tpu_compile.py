@@ -1,0 +1,128 @@
+"""The main path compiled for a described TPU v5e chip (nothing runs, no
+chip is needed): each Pallas kernel at the smoke model's real widths must
+lower to a TPU custom call, and the 2-layer full-width train step must fit
+one chip's HBM.  The topology is described inside a fixture, never at
+import, so only the worker that runs this file loads the TPU library."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from repro.kernels import (flash_attention as fa, ring_attention as ra,
+                           rmsnorm as rn, ssm_scan as ss, swiglu as sg)
+from repro.launch.mesh import make_mesh
+from repro.models import registry
+from repro.optim.adamw import AdamWConfig
+from repro.parallel.sharding import ShardingRules
+from repro.train import steps
+
+# what the compiler reports as one v5e chip's usable HBM
+V5E_HBM_BYTES = 15.75e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        # the TPU compiler otherwise writes its logs outside the checkout
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a described chip's executables cannot be read back from the
+        # persistent cache: keep them out of it
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield desc
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases():
+    """name -> (fn, arg shapes); h2o-danube-3-4b widths at seq 2048 (the
+    ring hop at 4096-token chunks), ssm_scan at falcon-mamba-7b's."""
+    cfg = registry.get_config("h2o-danube-3-4b")
+    ssm = registry.get_config("falcon-mamba-7b")
+    B, S, D, F = 4, 2048, cfg.d_model, cfg.d_ff
+    H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    C = 4096
+    di, ds = ssm.d_inner, ssm.ssm_state
+    f32 = jnp.float32
+    return {
+        "rmsnorm": (rn.rmsnorm, [(B, S, D), (D,)]),
+        "swiglu": (sg.swiglu, [(B, S, F), (B, S, F)]),
+        "flash_attention": (
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                               window=cfg.window),
+            [(1, S, H, hd), (1, S, Hk, hd), (1, S, Hk, hd)]),
+        "ring_step": (
+            lambda q, k, v, m, l, acc: ra.ring_step(
+                q, k, v, m, l, acc, q_start=C, k_start=0, k_valid=C),
+            [(1, C, H, hd), (1, C, Hk, hd), (1, C, Hk, hd),
+             ((1, C, H, 1), f32), ((1, C, H, 1), f32),
+             ((1, C, H, hd), f32)]),
+        "ssm_scan": (ss.ssm_scan,
+                     [(1, S, di), (1, S, di), (1, S, ds), (1, S, ds),
+                      ((di, ds), f32)]),
+    }
+
+
+@pytest.mark.parametrize("name", ["rmsnorm", "swiglu", "flash_attention",
+                                  "ring_step", "ssm_scan"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_cases()[name]
+    args = [_sds(one_chip, *s) if isinstance(s[0], tuple)
+            else _sds(one_chip, s) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_init_master_is_the_rounded_params_on_v5e(one_chip):
+    """The fp32 master must start at the bf16 params' values.  Fused with
+    the random draw, the TPU compiler emits the master from the unrounded
+    f32 draw in the same fusion as the params; the pipeline and the plain
+    step then start from different masters and part at step 1."""
+    bundle = registry.get_bundle("h2o-danube-3-4b", smoke=True,
+                                 param_dtype="bfloat16", dtype="bfloat16")
+    key = _sds(one_chip, (2,), jnp.uint32)
+    hlo = jax.jit(lambda k: steps.init_train_state(
+        bundle, jax.random.wrap_key_data(k))).lower(key).compile().as_text()
+    entry = hlo[hlo.index("ENTRY"):].splitlines()
+    fused_both = [ln for ln in entry if " fusion(" in ln
+                  and "= (f32" in ln and "bf16" in ln.split(" fusion(")[0]]
+    assert not fused_both, fused_both[0][:200]
+
+
+def test_full_width_train_step_fits_one_v5e(topo):
+    """The Trainer's plain step (AdamW, fp32 master, donated state) for
+    h2o-danube-3-4b at published widths, 2 layers, batch 4 x seq 2048."""
+    bundle = registry.get_bundle("h2o-danube-3-4b", num_layers=2)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    rules = ShardingRules(bundle.cfg, tp=1, dp_axes=("data",))
+    state = jax.eval_shape(lambda k: steps.init_train_state(bundle, k),
+                           jax.random.PRNGKey(0))
+    specs = steps.state_specs(bundle, rules, state, data_size=1)
+    state = jax.tree.map(
+        lambda s, p: _sds(NamedSharding(mesh, p), s.shape, s.dtype),
+        state, specs)
+    tok = _sds(NamedSharding(mesh, rules.batch_spec()), (4, 2048), jnp.int32)
+    step = steps.make_train_step(bundle, rules, AdamWConfig())
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            state, {"tokens": tok, "labels": tok}).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, (mem.argument_size_in_bytes,
+                                  mem.temp_size_in_bytes)
