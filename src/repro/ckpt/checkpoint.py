@@ -82,7 +82,7 @@ class AsyncCheckpointer:
     """Snapshot-on-call, write-on-thread.  One in-flight save at a time.
 
     Thread-safe: ``wait``/``save_async`` may race from different threads
-    (the train loop, a replan, a straggler hook).  The ``_thread`` swap
+    (the train loop, a replan, an adaptation).  The ``_thread`` swap
     and the keep-window ``_gc`` both run under ``_lock`` — the historical
     bug was a ``wait()`` returning concurrently with a fresh
     ``save_async()``: the finished thread's ``_thread = None`` clobbered

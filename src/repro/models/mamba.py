@@ -61,6 +61,7 @@ def _ssm_params(p, u, cfg: ModelConfig):
     return dt, Bc, Cc
 
 
+@jax.named_scope("ssm_scan")
 def selective_scan(u, dt, Bc, Cc, A, D, z, chunk: int = CHUNK):
     """u,dt,z: (B,S,di); Bc,Cc: (B,S,ds); A: (di,ds) -> y: (B,S,di)."""
     B, S, di = u.shape

@@ -120,16 +120,21 @@ def _remat(fn, cfg: ModelConfig):
 def _block_fwd(p, x, cfg: ModelConfig, kind: str):
     x = _constrain_act(x, cfg)
     if kind == "attn":
-        x = x + attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
-        h = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if cfg.n_experts:
-            y, aux = moe.moe_mlp(p["moe"], h, cfg)
-        else:
-            y, aux = mlp(p["mlp"], h, cfg), jnp.zeros((), jnp.float32)
-        return x + y, aux
+        with jax.named_scope("attn"):
+            x = x + attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                              cfg)
+        with jax.named_scope("mlp"):
+            h = rmsnorm(p["ln2"], x, cfg.norm_eps)
+            if cfg.n_experts:
+                y, aux = moe.moe_mlp(p["moe"], h, cfg)
+            else:
+                y, aux = mlp(p["mlp"], h, cfg), jnp.zeros((), jnp.float32)
+            return x + y, aux
     if kind == "ssm":
-        y = mamba.mamba_block(p["ssm"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
-        return x + y, jnp.zeros((), jnp.float32)
+        with jax.named_scope("ssm_block"):
+            y = mamba.mamba_block(p["ssm"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                                  cfg)
+            return x + y, jnp.zeros((), jnp.float32)
     if kind == "rec":
         x = x + griffin.rglru_block(
             p["rec"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
@@ -138,6 +143,7 @@ def _block_fwd(p, x, cfg: ModelConfig, kind: str):
     raise ValueError(kind)
 
 
+@jax.named_scope("embed")
 def _embed_tokens(params, tokens, cfg: ModelConfig,
                   extra_embeds: Optional[jax.Array]) -> jax.Array:
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.adtype)
@@ -146,6 +152,12 @@ def _embed_tokens(params, tokens, cfg: ModelConfig,
     return x
 
 
+@jax.named_scope("head_loss")
+def final_norm(params, x, cfg: ModelConfig) -> jax.Array:
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps)
+
+
+@jax.named_scope("head_loss")
 def _unembed(params, x, cfg: ModelConfig) -> jax.Array:
     w = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
     return jnp.einsum("bsd,dv->bsv", x, w,
@@ -199,7 +211,7 @@ def lm_forward(params, tokens, cfg: ModelConfig,
             x, a = fwd(params["layers"][i], x)
             aux = aux + a
     x = _constrain_act(x, cfg, cfg.head_act_sharding)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    x = final_norm(params, x, cfg)
     return _unembed(params, x, cfg), aux
 
 
@@ -249,7 +261,7 @@ def lm_features(params, tokens, cfg: ModelConfig,
             x, a = fwd(params["layers"][i], x)
             aux = aux + a
     x = _constrain_act(x, cfg, cfg.head_act_sharding)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    x = final_norm(params, x, cfg)
     w = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
     return x, w, aux
 

@@ -202,12 +202,16 @@ class Observability:
 
     # --------------------------------------------------------- step loop --
     def on_step(self, step: int, dt: float,
-                health: Optional[Dict[str, float]] = None) -> None:
+                health: Optional[Dict[str, float]] = None,
+                input_s: Optional[float] = None) -> None:
         """Per-step emission point; ``health`` is the exact dict
         ``Trainer.schedule_health()`` returned, so the gauges carry the
-        bit-identical floats the report must reproduce."""
+        bit-identical floats the report must reproduce.  ``input_s`` is
+        the host time of the step's batch and its ``device_put``."""
         if self.metrics is not None:
             self.metrics.gauge("step_time_s", dt)
+            if input_s is not None:
+                self.metrics.gauge("input_s", input_s)
             if health is not None:
                 self.metrics.gauge("observed_bubble",
                                    health["observed_bubble"])

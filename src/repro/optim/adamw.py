@@ -56,6 +56,7 @@ def global_norm(tree) -> jax.Array:
                         for l in leaves))
 
 
+@jax.named_scope("optimizer")
 def adamw_update(params: Any, grads: Any, state: dict, cfg: AdamWConfig):
     """Returns (new_params, new_state, metrics)."""
     count = state["count"] + 1

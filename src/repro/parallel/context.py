@@ -49,7 +49,8 @@ from repro.kernels.ring_attention import (NEG_INF, _ring_step_ref,
                                           unpad_chunks)
 from repro.models.config import ModelConfig
 from repro.models.layers import _qkv, mlp, rmsnorm
-from repro.models.transformer import _embed_tokens, _remat, _unembed
+from repro.models.transformer import (_embed_tokens, _remat, _unembed,
+                                      final_norm)
 from repro.train.steps import AUX_COEF, constrain, cross_entropy
 
 
@@ -114,10 +115,9 @@ def make_cp_loss_fn(cfg: ModelConfig, mesh, cp_chunks: Sequence[int]):
 
     vfold = jax.vmap(_fold)     # over the rank axis
 
-    def block_fwd(p, x):
-        """One attention block on the (cp, B, Cmax, D) rank layout —
-        ``transformer._block_fwd``'s attn branch with the ring inside."""
-        x = constrain(x, buf_spec)
+    def ring_attention(p, x):
+        """The pre-normed attention of x on the (cp, B, Cmax, D) rank
+        layout, the KV blocks passed around the ring."""
         h = rmsnorm(p["ln1"], x, cfg.norm_eps)
         q, k, v = jax.vmap(
             lambda hr, pr: _qkv(p["attn"], hr, cfg, pr))(h, pos)
@@ -137,12 +137,19 @@ def make_cp_loss_fn(cfg: ModelConfig, mesh, cp_chunks: Sequence[int]):
                 v = jnp.roll(v, 1, axis=0)
         o = (acc / jnp.maximum(l, 1e-30)).astype(x.dtype)
         o = o.reshape(cp, B, cmax, H * hd)
-        o = jnp.einsum("rbse,ed->rbsd", o, p["attn"]["wo"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        x = x + o
-        h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        y = jax.vmap(lambda hr: mlp(p["mlp"], hr, cfg))(h2)
-        return x + y, jnp.zeros((), jnp.float32)
+        return jnp.einsum("rbse,ed->rbsd", o, p["attn"]["wo"],
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+
+    def block_fwd(p, x):
+        """One attention block on the (cp, B, Cmax, D) rank layout —
+        ``transformer._block_fwd``'s attn branch with the ring inside."""
+        x = constrain(x, buf_spec)
+        with jax.named_scope("attn"):
+            x = x + ring_attention(p, x)
+        with jax.named_scope("mlp"):
+            h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
+            y = jax.vmap(lambda hr: mlp(p["mlp"], hr, cfg))(h2)
+            return x + y, jnp.zeros((), jnp.float32)
 
     def loss_fn(params, batch):
         tokens, labels = batch["tokens"], batch["labels"]
@@ -154,7 +161,7 @@ def make_cp_loss_fn(cfg: ModelConfig, mesh, cp_chunks: Sequence[int]):
                                 params["blocks"])
         aux = jnp.sum(auxs)
         x = unpad_chunks(xs, chunks)                       # (B, S, D)
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = final_norm(params, x, cfg)
         logits = _unembed(params, x, cfg)
         ce = cross_entropy(logits, labels)
         return ce + AUX_COEF * aux, {"ce": ce, "aux": aux}

@@ -36,8 +36,7 @@ from jax.sharding import PartitionSpec as P
 from repro.iccl.communicator import _note as _iccl_note
 from repro.models.config import ModelConfig
 from repro.models.transformer import (_block_fwd, _embed_tokens, _constrain_act,
-                                      _unembed)
-from repro.models.layers import rmsnorm
+                                      _unembed, final_norm)
 from repro.train.steps import cross_entropy, constrain, AUX_COEF
 
 
@@ -220,7 +219,7 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh, n_stages: int,
             _tick_mark(telemetry, t, out[-1, 0, 0, 0])
             j_out = t - (n_stages - 1)   # microbatch finishing this tick
             if 0 <= j_out < m:
-                h = rmsnorm(params["final_norm"], out[-1], cfg.norm_eps)
+                h = final_norm(params, out[-1], cfg)
                 logits = _unembed(params, h, cfg)
                 logits = constrain(logits, P(("data",), None, "model"))
                 loss_sum = loss_sum + cross_entropy(logits, labels[j_out])
@@ -320,7 +319,7 @@ def _make_pp_loss_fn_vpp(cfg: ModelConfig, mesh, n_stages: int, m: int,
             _tick_mark(telemetry, t, out[-1, -1, 0, 0, 0])
             j_out = t - (V - 1)          # microbatch finishing this tick
             if 0 <= j_out < m:
-                h = rmsnorm(params["final_norm"], out[-1, -1], cfg.norm_eps)
+                h = final_norm(params, out[-1, -1], cfg)
                 logits = _unembed(params, h, cfg)
                 logits = constrain(logits, P(("data",), None, "model"))
                 loss_sum = loss_sum + cross_entropy(logits, labels[j_out])

@@ -24,6 +24,7 @@ AUX_COEF = 0.01
 Z_COEF = 1e-4
 
 
+@jax.named_scope("head_loss")
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Stable CE over a (possibly vocab-sharded) logits tensor, fp32.
 
@@ -49,6 +50,7 @@ def constrain(x, spec):
         return x
 
 
+@jax.named_scope("head_loss")
 def _ce_sums(logits, labels):
     """(sum of (lse - gold), sum of lse^2, count) — chunk-combinable."""
     logits = logits.astype(jnp.float32)
@@ -75,6 +77,7 @@ def make_loss_fn(bundle: ArchBundle, rules: ShardingRules):
             fc = feats[:, :n * c].reshape(B, n, c, D).swapaxes(0, 1)
             lc = labels[:, :n * c].reshape(B, n, c).swapaxes(0, 1)
 
+            @jax.named_scope("head_loss")
             def body(acc, xs):
                 f, l = xs
                 logits = jnp.einsum("bsd,dv->bsv", f, w,

@@ -11,9 +11,6 @@
     and per-schedule bubble observations folded into the profile store as
     ``observed_stage_tick`` / ``observed_bubble`` entries — the closed
     loop the paper's predictor+planner need to track reality;
-  * straggler mitigation: per-step wall times feed an EWMA; sustained
-    degradation beyond ``straggler_factor`` triggers the replan hook with
-    a degraded ClusterSpec (``ClusterSpec.degrade``);
   * elastic scaling / node failure: ``replan(new_cluster)`` re-runs the
     automatic parallel planner on the surviving cluster — against the
     online profile once dense enough, with degradation-scaled observed
@@ -36,10 +33,11 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import planner as planner_mod
@@ -48,6 +46,7 @@ from repro.core.plan import ParallelPlan
 from repro.ckpt import checkpoint as ckpt
 from repro.data.pipeline import DataState, SyntheticTokens
 from repro.models.registry import ArchBundle
+from repro.obs import scopes
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import context, pipeline
 from repro.parallel.sharding import ShardingRules
@@ -61,8 +60,6 @@ class TrainerConfig:
     seq_len: int = 64
     ckpt_dir: str = "/tmp/repro_ckpt"
     ckpt_every: int = 10
-    straggler_factor: float = 1.5
-    straggler_patience: int = 5
     tp: int = 1
     # replan uses the accumulating online profile as the planner's cost
     # source once it holds at least this many folded layer-time
@@ -160,8 +157,6 @@ class Trainer:
             n_vision_tokens=bundle.cfg.n_vision_tokens)
         self.ckpt = ckpt.AsyncCheckpointer(cfg.ckpt_dir)
         self.telemetry: Optional[StageTelemetry] = None
-        self._ewma: Optional[float] = None
-        self._slow = 0
         self.replans = 0
         self.migrations = {"memory": 0, "checkpoint": 0}
         self._build()
@@ -201,6 +196,9 @@ class Trainer:
         return True
 
     def _build(self):
+        # the next step is the first of this program: it compiles, and it
+        # notes the program for the profile's scope table
+        self._first_step = True
         if self._pipeline_active():
             plan = self.plan
             m = plan.micro_batches
@@ -340,11 +338,9 @@ class Trainer:
 
         return {k: put(k, v) for k, v in np_batch.items()}
 
-    def run(self, n_steps: int,
-            on_straggler: Optional[Callable[["Trainer"], None]] = None
-            ) -> Dict[str, Any]:
+    def run(self, n_steps: int) -> Dict[str, Any]:
         try:
-            return self._run(n_steps, on_straggler)
+            return self._run(n_steps)
         except Exception as e:
             # a wedged schedule (planner/simulator ScheduleError) is the
             # flight recorder's primary customer: dump the last few
@@ -354,73 +350,77 @@ class Trainer:
                 self.obs.flight_dump("schedule-error")
             raise
 
-    def _run(self, n_steps: int,
-             on_straggler: Optional[Callable[["Trainer"], None]] = None
-             ) -> Dict[str, Any]:
+    def _run(self, n_steps: int) -> Dict[str, Any]:
+        """Each phase of a step runs in a ``trainer.*`` profiler span, on
+        the clock of the device ops in a ``jax.profiler`` trace."""
         losses = []
         for _ in range(n_steps):
             t0 = time.perf_counter()
-            np_batch = self.data.batch_at(self.step)
-            batch = self._device_batch(np_batch)
-            with jax.set_mesh(self.mesh):
+            with TraceAnnotation("trainer.batch"):
+                np_batch = self.data.batch_at(self.step)
+            with TraceAnnotation("trainer.put"):
+                batch = self._device_batch(np_batch)
+            input_s = time.perf_counter() - t0
+            if self._first_step:
+                scopes.note_program("train_step", self._jit,
+                                    scopes.abstract((self.state, batch)),
+                                    self.mesh)
+            with TraceAnnotation("trainer.dispatch"), \
+                    jax.set_mesh(self.mesh):
                 self.state, metrics = self._jit(self.state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            losses.append(float(metrics["loss"]))
-            self.step += 1
-            self.data.state.step = self.step
-            if self.profile_store is not None:
-                self._refine_profile(dt)
-                # bounded staleness ticks with or without a controller
-                # attached: a departed kind expires on schedule even when
-                # no policy/aggregator drives _maybe_adapt
-                self._expire_stale_profiles()
-            # --- straggler detection (observed vs EWMA-expected) ---
-            if self._ewma is None:
-                self._ewma = dt
-            else:
-                if dt > self.cfg.straggler_factor * self._ewma:
-                    self._slow += 1
-                else:
-                    self._slow = 0
-                self._ewma = 0.9 * self._ewma + 0.1 * dt
-                if self._slow >= self.cfg.straggler_patience:
-                    self._slow = 0
-                    if on_straggler is not None:
-                        on_straggler(self)
-            # --- autonomous adaptation (repro.adapt closed loop) ---
-            # membership events ride the same machinery with or without a
-            # policy: a node loss is a topology FACT, not a policy call,
-            # so the controller runs whenever there is a policy, an
-            # aggregator (followers must enter every broadcast), or a
-            # queued membership event
-            if self.policy is not None or self.aggregator is not None \
-                    or self._membership_pending:
-                # BOTH collectives of the loop — the telemetry gather and
-                # the decision broadcast inside _maybe_adapt — run HERE,
-                # unconditionally on a step cadence: self.step is
-                # identical across SPMD processes, so every process
-                # enters them together (policy/telemetry state may
-                # diverge per process and must never gate a collective)
-                on_cadence = (self.step
-                              % max(1, self.cfg.aggregate_every) == 0)
-                if self.policy is not None and self.aggregator is not None \
-                        and self.profile_store is not None and on_cadence:
-                    self._cluster_view = self.aggregator.gather(
-                        self.profile_store)
-                if on_cadence or \
-                        not getattr(self.aggregator, "collective", False):
-                    self._maybe_adapt()
-            # --- observability (repro.obs; default None = untouched) ---
-            if self.obs is not None:
-                self.obs.on_step(self.step, dt, self.schedule_health())
-            if self.step % self.cfg.ckpt_every == 0:
-                self.ckpt.save_async(self.step, self.state,
-                                     extra=self._ckpt_extra())
+            with TraceAnnotation("trainer.sync"):
+                jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                losses.append(float(metrics["loss"]))
+            with TraceAnnotation("trainer.after"):
+                self._after_step(dt, input_s)
         self.ckpt.wait()
         if self.profile_store is not None and self.profile_store.path:
             self.profile_store.save()
         return {"losses": losses, "step": self.step}
+
+    def _after_step(self, dt: float, input_s: float) -> None:
+        """Profile fold, adaptation, observability and checkpoint of the
+        step just synced."""
+        self.step += 1
+        self.data.state.step = self.step
+        if self.profile_store is not None:
+            self._refine_profile(dt)
+            # bounded staleness ticks with or without a controller
+            # attached: a departed kind expires on schedule even when
+            # no policy/aggregator drives _maybe_adapt
+            self._expire_stale_profiles()
+        self._first_step = False
+        # --- autonomous adaptation (repro.adapt closed loop) ---
+        # membership events ride the same machinery with or without a
+        # policy: a node loss is a topology FACT, not a policy call,
+        # so the controller runs whenever there is a policy, an
+        # aggregator (followers must enter every broadcast), or a
+        # queued membership event
+        if self.policy is not None or self.aggregator is not None \
+                or self._membership_pending:
+            # BOTH collectives of the loop — the telemetry gather and
+            # the decision broadcast inside _maybe_adapt — run HERE,
+            # unconditionally on a step cadence: self.step is
+            # identical across SPMD processes, so every process
+            # enters them together (policy/telemetry state may
+            # diverge per process and must never gate a collective)
+            on_cadence = (self.step
+                          % max(1, self.cfg.aggregate_every) == 0)
+            if self.policy is not None and self.aggregator is not None \
+                    and self.profile_store is not None and on_cadence:
+                self._cluster_view = self.aggregator.gather(
+                    self.profile_store)
+            if on_cadence or \
+                    not getattr(self.aggregator, "collective", False):
+                self._maybe_adapt()
+        # --- observability (repro.obs; default None = untouched) ---
+        if self.obs is not None:
+            self.obs.on_step(self.step, dt, self.schedule_health(),
+                             input_s=input_s)
+        if self.step % self.cfg.ckpt_every == 0:
+            self.ckpt.save_async(self.step, self.state,
+                                 extra=self._ckpt_extra())
 
     def _ckpt_extra(self) -> Dict[str, Any]:
         return {"data": self.data.state.to_dict(),
@@ -432,7 +432,7 @@ class Trainer:
         keyed by the exact workload shape), plus a per-layer estimate the
         ProfiledCostModel can interpolate.  The first step after a (re)build
         is excluded: it pays jit compilation, not steady-state time."""
-        if self._ewma is None:
+        if self._first_step:
             return
         from repro.profile.runner import device_kind
         dev = device_kind()
@@ -1162,7 +1162,3 @@ class Trainer:
             self._init_or_restore()   # restores + migrates the checkpoint
         if self.obs is not None:
             self.obs.on_migration(time.perf_counter() - t_mig, migrated)
-        # the rebuilt step recompiles on first use: restart the EWMA so the
-        # compile step is neither folded into the profile nor flagged slow
-        self._ewma = None
-        self._slow = 0

@@ -460,7 +460,7 @@ def test_e2e_metrics_validate_and_carry_the_loop(obs_e2e):
     assert run_id == obs_e2e["run"].run_id
     recs = read_jsonl(path)
     names = {r.get("name") for r in recs}
-    for name in ("step_time_s", "tick_s", "observed_bubble",
+    for name in ("step_time_s", "input_s", "tick_s", "observed_bubble",
                  "predicted_bubble", "iccl_calls", "iccl_bytes",
                  "adapt_events", "replans", "store_folds"):
         assert name in names, f"metric {name} never emitted"

@@ -126,19 +126,6 @@ def test_trainer_elastic_replan():
         assert all(np.isfinite(r["losses"]))
 
 
-def test_trainer_straggler_hook_fires():
-    mesh = make_mesh((1, 1), ("data", "model"))
-    b = registry.get_bundle("llama3-8b", smoke=True)
-    with tempfile.TemporaryDirectory() as d:
-        t = Trainer(b, mesh, TrainerConfig(global_batch=4, seq_len=32,
-                                           ckpt_dir=d, ckpt_every=100,
-                                           straggler_factor=0.0,
-                                           straggler_patience=2))
-        fired = []
-        t.run(5, on_straggler=lambda tr: fired.append(tr.step))
-        assert fired, "straggler hook never fired despite factor=0"
-
-
 # ------------------------------------------------------------------ iccl ---
 def test_iccl_collectives_single_axis():
     mesh = make_mesh((1,), ("x",))
