@@ -8,10 +8,10 @@ from typing import Optional
 import jax
 
 from repro.kernels import flash_attention as fa
-from repro.kernels import ref, rmsnorm as rn, ssm_scan as ss, swiglu as sg
+from repro.kernels import ref, rmsnorm as rn, swiglu as sg
 
 
-def _pallas() -> bool:
+def use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
@@ -19,7 +19,7 @@ def _pallas() -> bool:
 def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None):
-    if not _pallas():
+    if not use_pallas():
         return ref.flash_attention_ref(q, k, v, causal=causal,
                                        window=window, softcap=softcap)
     return fa.flash_attention(q, k, v, causal=causal, window=window,
@@ -28,20 +28,13 @@ def flash_attention(q, k, v, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def rmsnorm(x, scale, eps: float = 1e-5):
-    if not _pallas():
+    if not use_pallas():
         return ref.rmsnorm_ref(x, scale, eps)
     return rn.rmsnorm(x, scale, eps)
 
 
 @jax.jit
-def ssm_scan(u, dt, Bc, Cc, A):
-    if not _pallas():
-        return ref.ssm_scan_ref(u, dt, Bc, Cc, A)
-    return ss.ssm_scan(u, dt, Bc, Cc, A)
-
-
-@jax.jit
 def swiglu(g, u):
-    if not _pallas():
+    if not use_pallas():
         return ref.swiglu_ref(g, u)
     return sg.swiglu(g, u)

@@ -1,18 +1,22 @@
 """Mamba-1 block (falcon-mamba-7b): selective state-space model.
 
-Training path uses a chunked scan: sequential ``lax.scan`` over chunks with a
-parallel ``associative_scan`` inside each chunk — the TPU adaptation of the
-CUDA fused selective-scan (see kernels/ssm_scan.py for the Pallas version).
-The (B, chunk, d_inner, d_state) intermediate only materializes per chunk and
-d_inner is TP-sharded, keeping the working set VMEM-friendly.
+The selective scan runs the Pallas kernel pair of kernels/ssm_scan.py on
+the TPU (forward and backward, state in VMEM), per shard under
+``jax.shard_map`` over a mesh of more than one device.  Where the backend
+or the shapes do not suit the kernel it runs a chunked scan in jnp:
+sequential ``lax.scan`` over chunks with a parallel ``associative_scan``
+inside each chunk.
 
 Decode path is the O(1) recurrence (no KV cache — the reason long_500k runs).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops, ssm_scan as ss
 from repro.models.config import ModelConfig
 from repro.models.layers import _he
 
@@ -61,14 +65,13 @@ def _ssm_params(p, u, cfg: ModelConfig):
     return dt, Bc, Cc
 
 
-@jax.named_scope("ssm_scan")
-def selective_scan(u, dt, Bc, Cc, A, D, z, chunk: int = CHUNK):
-    """u,dt,z: (B,S,di); Bc,Cc: (B,S,ds); A: (di,ds) -> y: (B,S,di)."""
+def _chunked_scan(u, dt, Bc, Cc, A, chunk: int):
+    """The jnp scan: u,dt: (B,S,di); Bc,Cc: (B,S,ds); A: (di,ds) -> y
+    (B,S,di) fp32, before the D skip and the gate."""
     B, S, di = u.shape
     ds = Bc.shape[-1]
     nc = max(1, S // chunk)
     chunk = S // nc
-    uf = u.astype(jnp.float32)
 
     # per-step decay exponent and input: (B,S,di,ds)
     def chunk_body(h, xs):
@@ -87,11 +90,51 @@ def selective_scan(u, dt, Bc, Cc, A, D, z, chunk: int = CHUNK):
         return h_all[:, -1], y
 
     xs = tuple(a.reshape(B, nc, chunk, *a.shape[2:]).swapaxes(0, 1)
-               for a in (dt.astype(jnp.float32), uf,
+               for a in (dt.astype(jnp.float32), u.astype(jnp.float32),
                          Bc.astype(jnp.float32), Cc.astype(jnp.float32)))
     h0 = jnp.zeros((B, di, ds), jnp.float32)
     _, ys = jax.lax.scan(chunk_body, h0, xs)
-    y = ys.swapaxes(0, 1).reshape(B, S, di)
+    return ys.swapaxes(0, 1).reshape(B, S, di)
+
+
+def _kernel_scan(u, dt, Bc, Cc, A, chunk: int):
+    """The Pallas scan, or None where the sequence is not a multiple of
+    the kernel's row group or d_inner not a multiple of 128 lanes.
+
+    The compiler does not partition a Mosaic kernel, so over a mesh of
+    more than one device the kernel runs per shard under ``jax.shard_map``
+    over every axis of the mesh in scope: batch split over the other axes,
+    as many as divide it, innermost first, and d_inner over ``model`` where
+    that leaves whole 128-lane blocks; an axis that splits neither holds a
+    replica.  The scan is independent per (batch, d_inner), so the shards
+    need no exchange.  A step over several devices runs inside
+    ``jax.set_mesh``, as the Trainer's does."""
+    B, S, di = u.shape
+    if S % ss.GROUP or di % 128:
+        return None
+    scan = functools.partial(ss.ssm_scan, chunk=chunk)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return scan(u, dt, Bc, Cc, A)
+    n_tp = mesh.shape.get("model", 1)
+    tp = "model" if n_tp > 1 and di % (128 * n_tp) == 0 else None
+    dp, n_dp = (), 1
+    for a in reversed(mesh.axis_names):
+        if a != "model" and B % (n_dp * mesh.shape[a]) == 0:
+            dp, n_dp = (a,) + dp, n_dp * mesh.shape[a]
+    P = jax.sharding.PartitionSpec
+    seq, rows = P(dp, None, tp), P(dp, None, None)
+    return jax.shard_map(scan, in_specs=(seq, seq, rows, rows, P(tp, None)),
+                         out_specs=seq, check_vma=False)(u, dt, Bc, Cc, A)
+
+
+@jax.named_scope("ssm_scan")
+def selective_scan(u, dt, Bc, Cc, A, D, z, chunk: int = CHUNK):
+    """u,dt,z: (B,S,di); Bc,Cc: (B,S,ds); A: (di,ds) -> y: (B,S,di)."""
+    y = _kernel_scan(u, dt, Bc, Cc, A, chunk) if ops.use_pallas() else None
+    if y is None:
+        y = _chunked_scan(u, dt, Bc, Cc, A, chunk)
+    uf = u.astype(jnp.float32)
     y = y + uf * D[None, None]
     return (y * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype)
 

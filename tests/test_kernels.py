@@ -103,6 +103,140 @@ def test_ssm_scan(B, S, di, ds, chunk, dib):
                                rtol=2e-4, atol=2e-4)
 
 
+def _scan_inputs(B, S, di, ds, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    u = jax.random.normal(ks[0], (B, S, di))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, di)) - 1.0)
+    Bc = jax.random.normal(ks[2], (B, S, ds))
+    Cc = jax.random.normal(ks[3], (B, S, ds))
+    A = -jnp.exp(jax.random.normal(ks[4], (di, ds)) * 0.3)
+    dy = jax.random.normal(ks[5], (B, S, di))
+    return (u, dt, Bc, Cc, A), dy
+
+
+def _close(got, want, rel=1e-4):
+    """Within ``rel`` of the largest magnitude of ``want``."""
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=rel,
+                               atol=rel * float(np.max(np.abs(want))))
+
+
+@pytest.mark.parametrize("B,S,di,ds,chunk,dib", [
+    (1, 32, 128, 8, 32, 128),     # one chunk, one d_inner block
+    (2, 64, 256, 16, 16, 128),    # several chunks and d_inner blocks
+    (1, 48, 256, 4, 32, 128),     # padded to two chunks, several blocks
+    (2, 32, 128, 16, 16, 128),    # batch 2, one d_inner block
+], ids=["one_chunk", "chunks_blocks", "padded", "batch2"])
+def test_ssm_scan_grad(B, S, di, ds, chunk, dib):
+    """The custom-vjp scan and its gradient of u, dt, B, C and A against
+    the sequential oracle and jax.grad of it."""
+    args, dy = _scan_inputs(B, S, di, ds)
+    y, vjp = jax.vjp(lambda *a: ssm_scan(*a, chunk=chunk, di_block=dib,
+                                         interpret=True), *args)
+    y_ref, vjp_ref = jax.vjp(ref.ssm_scan_ref, *args)
+    _close(y, y_ref)
+    for got, want in zip(vjp(dy), vjp_ref(dy)):
+        _close(got, want)
+
+
+@pytest.mark.parametrize("S,di,kernel", [
+    (32, 128, True),
+    (40, 128, False),             # sequence not a multiple of 16 rows
+    (32, 96, False),              # d_inner not a multiple of 128 lanes
+], ids=["kernel", "fallback_seq", "fallback_lanes"])
+def test_selective_scan_dispatch(monkeypatch, S, di, kernel):
+    """``selective_scan`` takes the kernel where the shapes allow it and
+    the jnp chunked scan elsewhere; both give the jnp path's values and
+    gradients.  The test steers it onto the kernel path on the CPU, with
+    the kernels interpreted, and counts the kernel's calls."""
+    from repro.kernels import ops, ssm_scan as ss
+    from repro.models import mamba
+    (u, dt, Bc, Cc, A), dy = _scan_inputs(2, S, di, 4, seed=1)
+    ks = jax.random.split(jax.random.PRNGKey(2))
+    D, z = jax.random.normal(ks[0], (di,)), jax.random.normal(ks[1], u.shape)
+    args = (u, dt, Bc, Cc, A, D, z)
+
+    def run(*a):
+        return jax.vjp(lambda *x: mamba.selective_scan(*x, chunk=16), *a)
+
+    y_ref, vjp_ref = run(*args)
+    calls, scan = [], ss.ssm_scan
+
+    def interpreted(*a, **kw):
+        calls.append(a[0].shape)
+        return scan(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ss, "ssm_scan", interpreted)
+    y, vjp = run(*args)
+    assert calls == ([u.shape] if kernel else [])
+    _close(y, y_ref)
+    for got, want in zip(vjp(dy), vjp_ref(dy)):
+        _close(got, want)
+
+
+def test_selective_scan_shard_map_on_four_devices():
+    """The kernel path over meshes of four host devices runs under
+    ``jax.shard_map`` and gives the single-device values and gradients:
+    batch over data and d_inner over model; batch over (pod, data) inside
+    a vmap over pipeline stages; and a batch that data does not divide,
+    held as a replica (subprocess: the device count is set before JAX
+    starts)."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import functools
+import jax, jax.numpy as jnp, numpy as np
+from repro.kernels import ops, ssm_scan as ss
+from repro.launch.mesh import make_mesh
+from repro.models import mamba
+ops.use_pallas = lambda: True
+ss.ssm_scan = functools.partial(ss.ssm_scan, interpret=True)
+
+def inputs(lead, S=32, di=256, ds=4):
+    ks = jax.random.split(jax.random.PRNGKey(len(lead)), 8)
+    seq, rows = lead + (S, di), lead + (S, ds)
+    return ((jax.random.normal(ks[0], seq),
+             jax.nn.softplus(jax.random.normal(ks[1], seq) - 1.0),
+             jax.random.normal(ks[2], rows), jax.random.normal(ks[3], rows),
+             -jnp.exp(jax.random.normal(ks[4], (di, ds)) * 0.3),
+             jax.random.normal(ks[5], (di,)), jax.random.normal(ks[6], seq)),
+            jax.random.normal(ks[7], seq))
+
+def grads(stages):
+    def f(dy, *a):
+        scan = lambda *x: mamba.selective_scan(*x, chunk=16)
+        if stages:
+            scan = jax.vmap(scan, in_axes=(0, 0, 0, 0, None, None, 0))
+        y, vjp = jax.vjp(scan, *a)
+        return (y,) + vjp(dy)
+    return jax.jit(f)
+
+for shape, axes, lead in [((2, 2), ("data", "model"), (2,)),
+                          ((2, 2, 1), ("pod", "data", "model"), (2, 4)),
+                          ((4, 1), ("data", "model"), (2,))]:
+    args, dy = inputs(lead)
+    f = grads(len(lead) == 2)
+    want = f(dy, *args)
+    with jax.set_mesh(make_mesh(shape, axes)):
+        assert "shard_map" in str(jax.make_jaxpr(f)(dy, *args))
+        got = f(dy, *args)
+    for g, w in zip(got, want):
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5,
+                                   atol=1e-5 * float(np.max(np.abs(w))))
+print("SHARDED_SCAN_OK")
+"""
+    import subprocess
+    import sys
+    from pathlib import Path
+    r = subprocess.run([sys.executable, "-c", code],
+                       cwd=str(Path(__file__).resolve().parents[1]),
+                       capture_output=True, text=True, timeout=600)
+    assert "SHARDED_SCAN_OK" in r.stdout, r.stderr[-2000:]
+
+
 @pytest.mark.parametrize("shape", [(32, 128), (2, 64, 256)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_swiglu(shape, dtype):

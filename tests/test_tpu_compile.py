@@ -76,17 +76,113 @@ def _kernel_cases():
         "ssm_scan": (ss.ssm_scan,
                      [(1, S, di), (1, S, di), (1, S, ds), (1, S, ds),
                       ((di, ds), f32)]),
+        "ssm_scan_bwd": (
+            lambda u, dt, b, c, a, dy: jax.vjp(ss.ssm_scan, u, dt, b, c,
+                                               a)[1](dy),
+            [(1, S, di), ((1, S, di), f32), ((1, S, ds), f32),
+             ((1, S, ds), f32), ((di, ds), f32), ((1, S, di), f32)]),
     }
 
 
 @pytest.mark.parametrize("name", ["rmsnorm", "swiglu", "flash_attention",
-                                  "ring_step", "ssm_scan"])
+                                  "ring_step", "ssm_scan", "ssm_scan_bwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_cases()[name]
     args = [_sds(one_chip, *s) if isinstance(s[0], tuple)
             else _sds(one_chip, s) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_scan_kernels_count_for_the_scan_scope(one_chip, monkeypatch):
+    """The gradient of ``selective_scan``'s kernel path, compiled for a v5e:
+    every Mosaic call of the forward and backward kernels maps to the
+    ``ssm_scan`` scope, so the scan's device time cannot move to
+    ``unscoped``.  (Here and not beside the other scope tests: only one
+    test file may describe the chip.)"""
+    from repro.kernels import ops
+    from repro.models import mamba
+    from repro.obs import scopes
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    ssm = registry.get_config("falcon-mamba-7b")
+    S, di, ds = 2048, ssm.d_inner, ssm.ssm_state
+    f32 = jnp.float32
+    shapes = [(1, S, di), ((1, S, di), f32), ((1, S, ds), f32),
+              ((1, S, ds), f32), ((di, ds), f32), ((di,), f32), (1, S, di)]
+    args = [_sds(one_chip, *s) if isinstance(s[0], tuple)
+            else _sds(one_chip, s) for s in shapes]
+
+    def loss(*a):
+        with jax.named_scope("ssm_block"):
+            y = jax.checkpoint(mamba.selective_scan)(*a)
+        return jnp.sum(y.astype(f32))
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        *args).compile().as_text()
+    table = scopes.op_scopes(text)
+    calls = {ln.split(" = ")[0].split()[-1].lstrip("%"):
+             ln.split('custom_call_target="')[1].split('"')[0]
+             for ln in text.splitlines() if "custom-call(" in ln}
+    mosaic = [n for n, target in calls.items()
+              if target == "tpu_custom_call"]
+    assert {n.rsplit(".", 1)[0] for n in mosaic} == {"ssm_scan_fwd",
+                                                      "ssm_scan_bwd"}
+    assert {table.get(n) for n in mosaic} == {"ssm_scan"}
+
+
+@pytest.mark.parametrize("pp", [1, 2], ids=["data4", "pp2"])
+def test_mamba_train_step_compiles_on_four_v5e(topo, monkeypatch, pp):
+    """falcon-mamba's train step on the Trainer's mesh over four described
+    v5e chips, (data 4) or two pipeline stages on (pod 2, data 2), with
+    the scan on its kernel path.  The compiler refuses a Mosaic kernel
+    left to partitioning, so each kernel call must run per shard."""
+    from types import SimpleNamespace
+
+    from jax.sharding import PartitionSpec as P
+
+    from repro.ckpt import checkpoint as ckpt
+    from repro.kernels import ops
+    from repro.launch.mesh import make_train_mesh
+    from repro.parallel import pipeline
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    bundle = registry.get_bundle("falcon-mamba-7b", smoke=True, num_layers=2)
+    mesh = make_train_mesh(SimpleNamespace(pp=pp), topo.devices)
+    rules = ShardingRules(bundle.cfg, tp=1, dp_axes=("data",))
+    data = mesh.shape["data"]
+    state = jax.eval_shape(lambda k: steps.init_train_state(bundle, k),
+                           jax.random.PRNGKey(0))
+    tokens, batch_spec, loss_fn = (8, 64), rules.batch_spec(), None
+    if pp == 1:
+        specs = steps.state_specs(bundle, rules, state, data_size=data)
+    else:
+        # the Trainer's stacked state and its shardings for a 1 + 1 plan
+        layout = {"pp": pp, "vpp": 1, "virtual_layers": [1, 1],
+                  "stage_tp": [1, 1]}
+        state = jax.eval_shape(lambda s: ckpt.migrate(s, None, layout),
+                               state)
+        p_specs = pipeline.pp_param_specs(rules.param_specs(state["params"]))
+        specs = {"params": p_specs, "step": P(), "opt": {"count": P()}}
+        for k in ("m", "v", "master"):
+            if k in state["opt"]:
+                specs["opt"][k] = jax.tree.map(
+                    lambda sp, sh: rules.opt_state_spec(sp, sh.shape, data),
+                    p_specs, state["opt"][k])
+        loss_fn = pipeline.make_pp_loss_fn(bundle.cfg, mesh, pp, 2,
+                                           layers_per_stage=[1, 1])
+        tokens, batch_spec = (2, 4, 64), P(None, *batch_spec)
+    state = jax.tree.map(
+        lambda s, p: _sds(NamedSharding(mesh, p), s.shape, s.dtype),
+        state, specs)
+    tok = _sds(NamedSharding(mesh, batch_spec), tokens, jnp.int32)
+    step = steps.make_train_step(bundle, rules, AdamWConfig(),
+                                 loss_fn=loss_fn)
+    with jax.set_mesh(mesh):
+        text = jax.jit(step, donate_argnums=0).lower(
+            state, {"tokens": tok, "labels": tok}).compile().as_text()
+    kernels = {ln.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+               for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln}
+    assert kernels == {"ssm_scan_fwd", "ssm_scan_bwd"}
 
 
 def test_init_master_is_the_rounded_params_on_v5e(one_chip):

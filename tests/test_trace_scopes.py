@@ -102,6 +102,35 @@ def test_compiled_train_step_maps_to_each_layer(arch, want):
     assert _compiled_scopes(step, state, batch) == want
 
 
+def test_scan_kernels_map_to_the_scan_scope(monkeypatch):
+    """The falcon-mamba step on the scan's kernel path (interpreted on the
+    CPU): every instruction of the forward and backward kernels, remat
+    recompute included, maps to ``ssm_scan``, and the step's layers are
+    the same as on the jnp path."""
+    import functools
+    from repro.kernels import ops, ssm_scan as ss
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ss, "ssm_scan",
+                        functools.partial(ss.ssm_scan, interpret=True))
+    b = registry.get_bundle("falcon-mamba-7b", smoke=True)
+    rules = ShardingRules(b.cfg, tp=1, dp_axes=("data",))
+    state = steps.init_train_state(b, jax.random.PRNGKey(0))
+    batch = registry.make_batch(b.cfg, batch=2, seq=32)
+    step = steps.make_train_step(b, rules, AdamWConfig())
+    text = jax.jit(step).lower(state, batch).compile().as_text()
+    table = scopes.op_scopes(text)
+    kernel_ops = collections.defaultdict(set)
+    for line in text.splitlines():
+        instr, op = scopes._INSTR.match(line), scopes._OP_NAME.search(line)
+        for kernel in ("ssm_scan_fwd", "ssm_scan_bwd"):
+            if instr and op and f"/{kernel}/" in op.group(1):
+                kernel_ops[kernel].add(table.get(instr.group(1)))
+    assert kernel_ops == {"ssm_scan_fwd": {"ssm_scan"},
+                          "ssm_scan_bwd": {"ssm_scan"}}
+    assert set(table.values()) == {"embed", "ssm_block", "ssm_scan",
+                                   "head_loss", "optimizer"}
+
+
 def _loss_case(builder):
     b = registry.get_bundle("llama3-8b", smoke=True, num_layers=2)
     cfg = b.cfg
