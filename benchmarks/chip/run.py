@@ -106,6 +106,9 @@ def main(argv=None, checkout=harness.CHECKOUT, root=harness.ROOT,
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
     mem_peak = cell.memory_peak()
+    if cell.prediction is not None:
+        rec["predicted_peak_gb"] = max(cell.prediction.peak_mem_gb)
+        rec["peak_hbm_gb"] = mem_peak / 1e9
     log(f"memory_stats of the first chip {devices[0].memory_stats()}")
     canon = cell.canon
     cell.free()

@@ -6,8 +6,11 @@ the program keeps for its train step, as test data for the readers.
 
 Writes ``<dir>/<cell>.xplane.pb`` (the profiler's trace of ``--steps``
 steps, each in a ``train`` step span, after the cell's check steps) and
-``<dir>/<cell>.scopes.json`` (``repro.obs.scopes.table("train_step")``).
-Needs the chips the cell asks for.
+``<dir>/<cell>.scopes.json`` (``repro.obs.scopes.table("train_step")``)
+and ``<dir>/<cell>.record.json`` (the pipeline stages' chips, the
+planner's predicted step time and peak memory, and the fullest chip's
+measured peak, as a run's record has them).  Needs the chips the cell
+asks for.
 """
 from __future__ import annotations
 
@@ -49,6 +52,12 @@ def main(argv=None) -> int:
         shutil.copy(pb, out / f"{spec.name}.xplane.pb")
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
+    record = {"pods": cell.pods, "peak_hbm_gb": cell.memory_peak() / 1e9}
+    if cell.prediction is not None:
+        record["predicted_step_s"] = cell.prediction.iter_time
+        record["predicted_peak_gb"] = max(cell.prediction.peak_mem_gb)
+    (out / f"{spec.name}.record.json").write_text(
+        json.dumps(record, indent=1, sort_keys=True) + "\n")
     table = scopes.table("train_step")
     (out / f"{spec.name}.scopes.json").write_text(
         json.dumps(table, indent=0, sort_keys=True) + "\n")

@@ -33,6 +33,8 @@ import harness  # noqa: E402
 import trace_reduce  # noqa: E402
 
 SEED = 3_000_000_019          # above 2**31: seeds need not fit in 32 bits
+PIPELINE_READERS = ("stage_idle_max", "collective_exposed_ms",
+                    "predictor_err", "predictor_mem_err")
 
 
 def _bench():
@@ -313,34 +315,73 @@ import types
 import jax, jax.numpy as jnp
 import harness, run
 from repro.parallel import pipeline
+from repro.train import steps
 harness.require_chips = lambda n: jax.devices()[:n]
 harness.peak_for = lambda kind, root=None: {{"bf16_flops": 197e12}}
 harness.configure_jax = lambda: {{"compiled": 0, "cached": 0,
                                   "cache_dir": "off"}}
-if {broken!r}:
+fault = {fault!r}
+if fault == "no_hops":
     # the stage-to-stage hop over the pod axis delivers nothing
     pipeline.jnp = types.SimpleNamespace(**dict(
         vars(jnp), roll=lambda x, s, axis=0: jnp.zeros_like(x)))
+elif fault == "unchanged_state":
+    make_step = steps.make_train_step
+
+    def unchanged(*a, **k):
+        step = make_step(*a, **k)
+        return lambda state, batch: (state, step(state, batch)[1])
+    steps.make_train_step = unchanged
+elif fault == "half_batch":
+    # the first half of the rows, twice: the mean over half of the batch
+    make_loss = pipeline.make_pp_loss_fn
+
+    def first_half(v):
+        rows = v.reshape((-1,) + v.shape[2:])
+        half = rows[: rows.shape[0] // 2]
+        return jnp.concatenate([half, half]).reshape(v.shape)
+
+    def halved(*a, **k):
+        loss = make_loss(*a, **k)
+        return lambda params, batch: loss(
+            params, {{n: first_half(v) for n, v in batch.items()}})
+    pipeline.make_pp_loss_fn = halved
 tmp = Path({tmp!r})
 sys.exit(run.main(["--workload", "smoke-dense.pp2-b8s64", "--seed",
-                   "{seed}", "--seconds", "0.5", "--trace", "0"],
+                   "{seed}", "--seconds", "0.5", "--trace", "{trace}"],
                   checkout=tmp, root=tmp / "benchmarks" / "chip"))
 """
 
 
-@pytest.mark.parametrize("broken", [False, True],
-                         ids=["pipeline", "pipeline_without_hops"])
-def test_pipeline_cell_and_a_lost_exchange(tmp_path, broken):
-    """The pp=2 plan on four CPU devices checks correct; with the pod-axis
-    hop left out it does not."""
+@pytest.mark.parametrize("fault", [None, "no_hops", "unchanged_state",
+                                   "half_batch"],
+                         ids=["pipeline", "pipeline_without_hops",
+                              "pipeline_unchanged_state",
+                              "pipeline_half_batch"])
+def test_pipeline_cell_and_a_lost_exchange(tmp_path, fault):
+    """The pp=2 plan on four CPU devices checks correct, traced, and its
+    record gives the predictor's step time to its reader; with the
+    pod-axis hop left out, the state returned unchanged, or half of the
+    batch left out, it does not check correct."""
     make_checkout(tmp_path, [("smoke-dense", "pp2-b8s64", 4)])
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    for m in bench["per_layer"]:
+        if m["name"] in PIPELINE_READERS:
+            m["workloads"].append("smoke-dense.pp2-b8s64")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     code = PIPELINE_CHILD.format(chip=str(tmp_path / "benchmarks" / "chip"),
                                  src=str(REPO / "src"), tmp=str(tmp_path),
-                                 broken=broken, seed=SEED)
+                                 fault=fault, seed=SEED,
+                                 trace=int(fault is None))
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
-    assert res["correct"] is (not broken), res["checks"]
+    assert res["correct"] is (fault is None), res["checks"]
+    if fault is None:
+        # a CPU trace has no device plane: only the host clock's reader
+        assert res["metrics"]["predictor_err"]["value"] > 0
+        assert not {"stage_idle_max", "collective_exposed_ms"} & \
+            set(res["metrics"])
