@@ -28,20 +28,26 @@ class LayerCost:
 def layer_cost(cfg: ModelConfig, seq_len: int) -> LayerCost:
     """Cost of ONE transformer layer (mean over kinds for hybrid)."""
     D, F = cfg.d_model, cfg.d_ff
-    H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     kinds = cfg.layer_kinds()
 
-    def one(kind: str):
+    def one(kind: str, i: int):
         if kind == "attn":
-            proj = 2.0 * D * (H * hd + 2 * Hk * hd + H * hd)
+            proj = 2.0 * cfg.attn_params()
             kv = min(seq_len, cfg.window) if cfg.window else seq_len
-            attn = 2.0 * 2 * H * hd * kv
+            attn = cfg.attn_flops(kv)
             mats = 3 if cfg.act in ("swiglu", "geglu") else 2
+            if cfg.sigmoid_moe:
+                # dropless: the held experts' expected rows, no capacity
+                mlp = 2.0 * cfg.mlp_params(i, active_only=True)
+                params = cfg.attn_params() + cfg.mlp_params(i)
+                act_e = (cfg.top_k * cfg.experts_held_ / cfg.n_experts
+                         if cfg.is_moe_layer(i) else 1)
+                width = cfg.d_expert_ if cfg.is_moe_layer(i) else F
+                return proj + attn + mlp, params, D * 4 + width * act_e
             act_e = cfg.top_k if cfg.n_experts else 1
             mlp = 2.0 * mats * D * F * act_e * (cfg.capacity_factor
                                                 if cfg.n_experts else 1.0)
-            params = (D * (H + 2 * Hk) * hd + H * hd * D
-                      + mats * D * F * (cfg.n_experts or 1))
+            params = cfg.attn_params() + mats * D * F * (cfg.n_experts or 1)
             acts = (D * 4 + F * act_e)
         elif kind == "ssm":
             di, ds, dr = cfg.d_inner, cfg.ssm_state, cfg.dt_rank_
@@ -63,8 +69,8 @@ def layer_cost(cfg: ModelConfig, seq_len: int) -> LayerCost:
         return proj + attn + mlp, params, acts
 
     tot_f = tot_p = tot_a = 0.0
-    for k in kinds:
-        f, p, a = one(k)
+    for i, k in enumerate(kinds):
+        f, p, a = one(k, i)
         tot_f += f
         tot_p += p
         tot_a += a
@@ -86,9 +92,8 @@ def attention_flops_fraction(cfg: ModelConfig, seq_len: int) -> float:
     remaining ``1 - fraction`` is per-token linear work.  Zero for
     SSM/recurrent layers (no KV-dependent term), so hybrid stacks get the
     attn-layer-weighted mean, consistent with ``layer_cost``'s averaging."""
-    H, hd = cfg.n_heads, cfg.hd
     kv = min(seq_len, cfg.window) if cfg.window else seq_len
-    attn_one = 2.0 * 2 * H * hd * kv
+    attn_one = cfg.attn_flops(kv)
     kinds = cfg.layer_kinds()
     total = layer_cost(cfg, seq_len).flops_fwd * len(kinds)
     attn_total = attn_one * sum(k == "attn" for k in kinds)

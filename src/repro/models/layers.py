@@ -1,5 +1,6 @@
 """Core transformer layers: norms, RoPE, GQA/MQA attention (train + cached
-decode), dense MLPs.  Pure-functional: params are plain dict pytrees.
+decode), multi-head latent attention (train), dense MLPs.  Pure-functional:
+params are plain dict pytrees.
 
 All matmuls accumulate in fp32 (``preferred_element_type``) which mirrors MXU
 behaviour on TPU; activations are cast back to ``cfg.dtype``.
@@ -49,6 +50,19 @@ def apply_rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope_pairs(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """RoPE in the pairwise (interleaved) layout: rotates (x[2i], x[2i+1])
+    by pos * freq_i.  x: (..., S, H, hd); pos: (S,)."""
+    hd = x.shape[-1]
+    ang = pos[..., None].astype(jnp.float32) * rope_freqs(hd, theta)
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], hd // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 # ------------------------------------------------------------ attention ----
@@ -104,7 +118,8 @@ def _scores_mask(qpos, kpos, window, causal):
 
 
 def _sdpa(q, k, v, mask, cfg: ModelConfig):
-    """q:(B,Sq,H,hd) k/v:(B,Sk,Hk,hd)  mask:(Sq,Sk) -> (B,Sq,H,hd)."""
+    """q:(B,Sq,H,hd) k:(B,Sk,Hk,hd) v:(B,Sk,Hk,dv)  mask:(Sq,Sk)
+    -> (B,Sq,H,dv)."""
     B, Sq, H, hd = q.shape
     Hk = k.shape[2]
     G = H // Hk
@@ -119,35 +134,73 @@ def _sdpa(q, k, v, mask, cfg: ModelConfig):
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", w, v,
                    preferred_element_type=jnp.float32).astype(q.dtype)
-    return o.reshape(B, Sq, H, hd)
+    return o.reshape(B, Sq, H, v.shape[-1])
+
+
+def device_memory_bytes() -> int:
+    """Memory of one device, from the backend; 16 GiB, one TPU v5e's,
+    where the backend does not say (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 16 * 2 ** 30))
+
+
+def q_chunk(cfg: ModelConfig, B: int, H: int, Sq: int, Sk: int) -> int:
+    """Query rows per chunk of the attention core; 0 = unchunked.
+
+    ``cfg.attn_chunk`` sets it.  Otherwise the core chunks when the float32
+    scores of the whole sequence, B*H*Sq*Sk*4 bytes shared over the mesh's
+    devices, would take more than a fifth of a device's memory, with the
+    largest power-of-two chunk whose scores take at most a twenty-fourth
+    (danube at 4 x 2048, 2.1 GB, stays whole; at 1 x 8192, 8.6 GB, it
+    runs in chunks of 512 queries on a 16 GB chip)."""
+    if cfg.attn_chunk:
+        return cfg.attn_chunk if Sq > cfg.attn_chunk else 0
+    mem = device_memory_bytes()
+    row = B * H * Sk * 4 / max(jax.sharding.get_abstract_mesh().size, 1)
+    if row * Sq <= mem / 5:
+        return 0
+    c = Sq
+    while c > 1 and (c * row > mem / 24 or Sq % c):
+        c //= 2
+    return c
+
+
+def attention_core(q, k, v, pos, cfg: ModelConfig, *, causal: bool = True):
+    """Softmax attention of q (B,S,H,hd) over k (B,S,Hk,hd), v (B,S,Hk,dv)
+    at positions ``pos`` (S,), -> (B,S,H,dv).  Where ``q_chunk`` says so it
+    runs over chunks of queries under the scope ``attn_core``, each chunk
+    rematerialised, so that the backward pass holds one chunk's scores at
+    a time."""
+    B, S, H, hd = q.shape
+    chunk = q_chunk(cfg, B, H, S, k.shape[1])
+    if not chunk:
+        mask = _scores_mask(pos, pos, cfg.window, causal)
+        return _sdpa(q, k, v, mask, cfg)
+
+    @jax.checkpoint
+    def one(qc, i):
+        qpos = jax.lax.dynamic_slice_in_dim(pos, i * chunk, chunk)
+        mask = (pos[None, :] <= qpos[:, None]) if causal else \
+            jnp.ones((chunk, S), bool)
+        if cfg.window is not None:
+            mask &= pos[None, :] > qpos[:, None] - cfg.window
+        return _sdpa(qc, k, v, mask, cfg)
+
+    with jax.named_scope("attn_core"):
+        n = S // chunk
+        qs = q.reshape(B, n, chunk, H, hd).transpose(1, 0, 2, 3, 4)
+        os = jax.lax.map(lambda a: one(*a), (qs, jnp.arange(n)))
+        return os.transpose(1, 0, 2, 3, 4).reshape(B, S, H, v.shape[-1])
 
 
 def attention(p, x, cfg: ModelConfig, *, causal: bool = True,
               pos_offset: int = 0, return_kv: bool = False):
-    """Full-sequence attention (train / prefill).  Optionally q-chunked to
-    bound the (B,H,Sq,Sk) score materialization (memory-roofline lever)."""
+    """Full-sequence attention (train / prefill); its core bounds the
+    (B,H,Sq,Sk) scores it holds (``attention_core``)."""
     B, S, D = x.shape
     pos = jnp.arange(S) + pos_offset
     q, k, v = _qkv(p, x, cfg, pos)
-    chunk = cfg.attn_chunk
-    if not chunk or S <= chunk:
-        mask = _scores_mask(pos, pos, cfg.window, causal)
-        o = _sdpa(q, k, v, mask, cfg)
-    else:
-        n = S // chunk
-
-        def body(c, qc):
-            i, = c
-            qpos = i * chunk + jnp.arange(chunk) + pos_offset
-            mask = (pos[None, :] <= qpos[:, None]) if causal else \
-                jnp.ones((chunk, S), bool)
-            if cfg.window is not None:
-                mask &= pos[None, :] > qpos[:, None] - cfg.window
-            return (i + 1,), _sdpa(qc, k, v, mask, cfg)
-
-        qs = q.reshape(B, n, chunk, cfg.n_heads, cfg.hd).transpose(1, 0, 2, 3, 4)
-        _, os = jax.lax.scan(body, (jnp.int32(0),), qs)
-        o = os.transpose(1, 0, 2, 3, 4).reshape(B, S, cfg.n_heads, cfg.hd)
+    o = attention_core(q, k, v, pos, cfg, causal=causal)
     o = o.reshape(B, S, cfg.n_heads * cfg.hd)
     out = jnp.einsum("bse,ed->bsd", o, p["wo"],
                      preferred_element_type=jnp.float32).astype(x.dtype)
@@ -155,6 +208,53 @@ def attention(p, x, cfg: ModelConfig, *, causal: bool = True,
     if return_kv:
         return out, k, v
     return out
+
+
+# ------------------------------------------ multi-head latent attention ----
+# DeepSeek's kv_a_layernorm keeps its modeling code's default eps; the
+# configuration's rms_norm_eps is the layer norms'
+LATENT_NORM_EPS = 1e-6
+
+
+def init_mla(key, cfg: ModelConfig) -> dict:
+    D, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": _he(ks[0], (D, H * cfg.qk_hd), cfg.pdtype),
+        "wkv_a": _he(ks[1], (D, r + cfg.qk_rope_dim), cfg.pdtype),
+        "kv_norm": init_rmsnorm(r, cfg.pdtype),
+        "wkv_b": _he(ks[2], (r, H * (cfg.qk_nope_dim + cfg.v_hd)),
+                     cfg.pdtype),
+        "wo": _he(ks[3], (H * cfg.v_hd, D), cfg.pdtype),
+    }
+
+
+def mla(p, x, cfg: ModelConfig):
+    """Multi-head latent attention (DeepSeek-V3, no q LoRA), causal:
+    q = x Wq -> (S,H,nope+rope); [c, k_pe] = x Wkv_a, c = RMSNorm(c);
+    [k_nope, v] = c Wkv_b; pairwise RoPE on q_pe and on the one k_pe that
+    every head shares; softmax scale 1/sqrt(nope+rope); o Wo."""
+    B, S, _ = x.shape
+    H, nope, r = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    pos = jnp.arange(S)
+
+    def proj(a, w):
+        return jnp.einsum("bsd,de->bse", a, w,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+
+    q = proj(x, p["wq"]).reshape(B, S, H, cfg.qk_hd)
+    ckv = proj(x, p["wkv_a"])
+    c = rmsnorm(p["kv_norm"], ckv[..., :r], LATENT_NORM_EPS)
+    k_pe = apply_rope_pairs(ckv[..., None, r:], pos, cfg.rope_theta)
+    kv = proj(c, p["wkv_b"]).reshape(B, S, H, nope + cfg.v_hd)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope_pairs(q[..., nope:], pos, cfg.rope_theta)],
+        axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, cfg.qk_rope_dim))],
+        axis=-1)
+    o = attention_core(q, k, kv[..., nope:], pos, cfg)
+    return proj(o.reshape(B, S, H * cfg.v_hd), p["wo"])
 
 
 # ----------------------------------------------------- cached decoding -----

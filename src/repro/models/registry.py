@@ -25,6 +25,7 @@ ARCH_IDS = (
     "llama3-8b", "qwen3-14b", "nemotron-4-15b", "h2o-danube-3-4b",
     "falcon-mamba-7b", "phi-3-vision-4.2b", "mixtral-8x7b",
     "phi3.5-moe-42b-a6.6b", "recurrentgemma-9b", "whisper-tiny",
+    "moonlight-16b-a3b",
 )
 
 _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")

@@ -2,8 +2,10 @@
 
 Uniform-kind stacks (dense, moe, ssm) are ``lax.scan``-ed over a stacked
 layer dim so the lowered HLO is O(1) in depth (critical for the 512-device
-dry-run compile).  Hybrid stacks (recurrentgemma) are unrolled because the
-block kind alternates.
+dry-run compile); leading dense layers before an expert stack
+(``cfg.first_dense``, Moonlight's layer 0) run unrolled ahead of the scan.
+Hybrid stacks (recurrentgemma) are unrolled because the block kind
+alternates.
 
 Public entry points:
   init_lm(key, cfg)                          -> params
@@ -22,22 +24,31 @@ import jax.numpy as jnp
 from repro.models import griffin, mamba, moe
 from repro.models.config import ModelConfig
 from repro.models.layers import (_he, attention, decode_attention, init_attention,
-                                 init_kv_cache, init_mlp, init_rmsnorm, mlp,
-                                 rmsnorm)
+                                 init_kv_cache, init_mla, init_mlp,
+                                 init_rmsnorm, mla, mlp, rmsnorm)
 
 
 # ------------------------------------------------------------------ init ---
-def _init_block(key, cfg: ModelConfig, kind: str) -> dict:
+def _init_block(key, cfg: ModelConfig, kind: str, experts: bool = True) -> dict:
     D = cfg.d_model
     ks = jax.random.split(key, 2)
     p: Dict[str, Any] = {"ln1": init_rmsnorm(D, cfg.pdtype)}
     if kind == "attn":
-        p["attn"] = init_attention(ks[0], cfg)
-        p["ln2"] = init_rmsnorm(D, cfg.pdtype)
-        if cfg.n_experts:
-            p["moe"] = moe.init_moe(ks[1], cfg)
+        if cfg.mla:
+            p["mla"] = init_mla(ks[0], cfg)
         else:
+            p["attn"] = init_attention(ks[0], cfg)
+        p["ln2"] = init_rmsnorm(D, cfg.pdtype)
+        if not (cfg.n_experts and experts):
             p["mlp"] = init_mlp(ks[1], cfg)
+        elif cfg.sigmoid_moe:
+            k_moe, k_shared = jax.random.split(ks[1])
+            p["moe"] = moe.init_moe_share(k_moe, cfg)
+            if cfg.n_shared_experts:
+                p["shared"] = init_mlp(
+                    k_shared, cfg, d_ff=cfg.d_expert_ * cfg.n_shared_experts)
+        else:
+            p["moe"] = moe.init_moe(ks[1], cfg)
     elif kind == "ssm":
         p["ssm"] = mamba.init_mamba(ks[0], cfg)
     elif kind == "rec":
@@ -70,8 +81,13 @@ def init_lm(key, cfg: ModelConfig) -> dict:
                                 cfg.pdtype)
     if len(set(kinds)) == 1 and cfg.scan_layers:
         keys = jax.random.split(k_blocks, cfg.num_layers)
+        n_dense = cfg.first_dense
+        if n_dense:
+            params["dense"] = [_init_block(keys[i], cfg, kinds[0],
+                                           experts=False)
+                               for i in range(n_dense)]
         params["blocks"] = jax.vmap(
-            lambda k: _init_block(k, cfg, kinds[0]))(keys)
+            lambda k: _init_block(k, cfg, kinds[0]))(keys[n_dense:])
         params["_stacked"] = jnp.zeros(())  # marker (scalar keeps pytree sane)
     elif cfg.family == "hybrid" and cfg.scan_layers:
         pat, n_groups, tail_kinds = _hybrid_layout(cfg)
@@ -117,30 +133,48 @@ def _remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn)
 
 
-def _block_fwd(p, x, cfg: ModelConfig, kind: str):
+def _block(p, x, cfg: ModelConfig, kind: str):
+    """One block: (x, load-balance aux, counters) where the counters, of a
+    sigmoid-routed expert layer only, are ``moe.moe_share``'s."""
     x = _constrain_act(x, cfg)
+    stats: Dict[str, Any] = {}
     if kind == "attn":
-        with jax.named_scope("attn"):
-            x = x + attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
-                              cfg)
+        if "mla" in p:
+            with jax.named_scope("mla"):
+                x = x + mla(p["mla"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
+        else:
+            with jax.named_scope("attn"):
+                x = x + attention(p["attn"],
+                                  rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
         with jax.named_scope("mlp"):
             h = rmsnorm(p["ln2"], x, cfg.norm_eps)
-            if cfg.n_experts:
+            aux = jnp.zeros((), jnp.float32)
+            if "moe" in p and cfg.sigmoid_moe:
+                y, stats = moe.moe_share(p["moe"], h, cfg)
+                if "shared" in p:
+                    y = y + mlp(p["shared"], h, cfg)
+            elif "moe" in p:
                 y, aux = moe.moe_mlp(p["moe"], h, cfg)
             else:
-                y, aux = mlp(p["mlp"], h, cfg), jnp.zeros((), jnp.float32)
-            return x + y, aux
+                y = mlp(p["mlp"], h, cfg)
+            return x + y, aux, stats
     if kind == "ssm":
         with jax.named_scope("ssm_block"):
             y = mamba.mamba_block(p["ssm"], rmsnorm(p["ln1"], x, cfg.norm_eps),
                                   cfg)
-            return x + y, jnp.zeros((), jnp.float32)
+            return x + y, jnp.zeros((), jnp.float32), stats
     if kind == "rec":
         x = x + griffin.rglru_block(
             p["rec"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg)
         y = mlp(p["mlp"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
-        return x + y, jnp.zeros((), jnp.float32)
+        return x + y, jnp.zeros((), jnp.float32), stats
     raise ValueError(kind)
+
+
+def _block_fwd(p, x, cfg: ModelConfig, kind: str):
+    """One block: (x, load-balance aux)."""
+    x, aux, _ = _block(p, x, cfg, kind)
+    return x, aux
 
 
 @jax.named_scope("embed")
@@ -164,25 +198,36 @@ def _unembed(params, x, cfg: ModelConfig) -> jax.Array:
                       preferred_element_type=jnp.float32)
 
 
-def lm_forward(params, tokens, cfg: ModelConfig,
-               extra_embeds: Optional[jax.Array] = None
-               ) -> Tuple[jax.Array, jax.Array]:
-    """tokens: (B,S) int32 -> (logits (B,S_total,V) fp32, aux_loss)."""
+def _trunk(params, tokens, cfg: ModelConfig,
+           extra_embeds: Optional[jax.Array] = None):
+    """Embedding, blocks and final norm: (features (B,S,D), aux, counters).
+    The counters of the expert layers are summed over the layers
+    (``moe_rows``) and maxed (``moe_rows_max``)."""
     kinds = cfg.layer_kinds()
     x = _embed_tokens(params, tokens, cfg, extra_embeds)
+    stats: Dict[str, Any] = {}
+
+    def blk(kind):
+        fwd = functools.partial(_block, cfg=cfg, kind=kind)
+        return _remat(fwd, cfg) if cfg.remat else fwd
 
     if "blocks" in params:
-        kind = kinds[0]
-        fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-        if cfg.remat:
-            fwd = _remat(fwd, cfg)
+        fwd = blk(kinds[0])
+        lead = jnp.zeros((), jnp.float32)
+        for p in params.get("dense", ()):
+            x, a, _ = fwd(p, x)
+            lead = lead + a
 
         def body(x, p):
-            y, aux = fwd(p, x)
-            return y, aux
+            y, aux, st = fwd(p, x)
+            return y, (aux, st)
 
-        x, auxs = jax.lax.scan(body, x, params["blocks"])
-        aux = jnp.sum(auxs)
+        x, (auxs, st) = jax.lax.scan(body, x, params["blocks"])
+        # a stack without a dense prefix keeps the program it had
+        aux = jnp.sum(auxs) + lead if "dense" in params else jnp.sum(auxs)
+        if st:
+            stats = {"moe_rows": jnp.sum(st["rows"]),
+                     "moe_rows_max": jnp.max(st["rows_max"])}
     elif "groups" in params:
         pat, n_groups, tail_kinds = _hybrid_layout(cfg)
 
@@ -197,77 +242,47 @@ def lm_forward(params, tokens, cfg: ModelConfig,
         x, auxs = jax.lax.scan(lambda x, p: gf(p, x), x, params["groups"])
         aux = jnp.sum(auxs)
         for i, kind in enumerate(tail_kinds):
-            fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-            if cfg.remat:
-                fwd = _remat(fwd, cfg)
-            x, a = fwd(params["tail"][i], x)
+            x, a, _ = blk(kind)(params["tail"][i], x)
             aux = aux + a
     else:
         aux = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(kinds):
-            fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-            if cfg.remat:
-                fwd = _remat(fwd, cfg)
-            x, a = fwd(params["layers"][i], x)
+            x, a, _ = blk(kind)(params["layers"][i], x)
             aux = aux + a
     x = _constrain_act(x, cfg, cfg.head_act_sharding)
-    x = final_norm(params, x, cfg)
+    return final_norm(params, x, cfg), aux, stats
+
+
+def lm_forward(params, tokens, cfg: ModelConfig,
+               extra_embeds: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """tokens: (B,S) int32 -> (logits (B,S_total,V) fp32, aux_loss)."""
+    x, aux, _ = _trunk(params, tokens, cfg, extra_embeds)
     return _unembed(params, x, cfg), aux
 
 
 def lm_features(params, tokens, cfg: ModelConfig,
                 extra_embeds: Optional[jax.Array] = None):
-    """Forward WITHOUT the unembed: (features (B,S,D), unembed_w, aux).
-    Lets the loss fuse the head into sequence chunks so the (B,S,V) logits
-    never materialize (the dominant temp for 150k-256k vocabs)."""
-    logits_fn = _unembed  # noqa: F841  (doc pointer)
-    kinds = cfg.layer_kinds()  # mirror lm_forward
-    import repro.models.transformer as _self
-    full = lm_forward.__wrapped__ if hasattr(lm_forward, "__wrapped__")         else None
-    # re-run the block stack exactly as lm_forward does, minus the head
-    x = _embed_tokens(params, tokens, cfg, extra_embeds)
-    if "blocks" in params:
-        kind = kinds[0]
-        fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-        if cfg.remat:
-            fwd = _remat(fwd, cfg)
-        x, auxs = jax.lax.scan(lambda x, p: fwd(p, x), x, params["blocks"])
-        aux = jnp.sum(auxs)
-    elif "groups" in params:
-        pat, n_groups, tail_kinds = _hybrid_layout(cfg)
-
-        def group_fwd(p, x):
-            aux = jnp.zeros((), jnp.float32)
-            for i, kind in enumerate(pat):
-                x, a = _block_fwd(p[f"b{i}"], x, cfg, kind)
-                aux = aux + a
-            return x, aux
-
-        gf = _remat(group_fwd, cfg) if cfg.remat else group_fwd
-        x, auxs = jax.lax.scan(lambda x, p: gf(p, x), x, params["groups"])
-        aux = jnp.sum(auxs)
-        for i, kind in enumerate(tail_kinds):
-            fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-            if cfg.remat:
-                fwd = _remat(fwd, cfg)
-            x, a = fwd(params["tail"][i], x)
-            aux = aux + a
-    else:
-        aux = jnp.zeros((), jnp.float32)
-        for i, kind in enumerate(kinds):
-            fwd = functools.partial(_block_fwd, cfg=cfg, kind=kind)
-            if cfg.remat:
-                fwd = _remat(fwd, cfg)
-            x, a = fwd(params["layers"][i], x)
-            aux = aux + a
-    x = _constrain_act(x, cfg, cfg.head_act_sharding)
-    x = final_norm(params, x, cfg)
+    """Forward WITHOUT the unembed: (features (B,S,D), unembed_w, aux,
+    counters).  Lets the loss fuse the head into sequence chunks so the
+    (B,S,V) logits never materialize (the dominant temp for 150k-256k
+    vocabs)."""
+    x, aux, stats = _trunk(params, tokens, cfg, extra_embeds)
     w = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    return x, w, aux
+    return x, w, aux, stats
+
+
+def _check_servable(cfg: ModelConfig) -> None:
+    if cfg.mla or cfg.first_dense:
+        raise NotImplementedError(
+            f"{cfg.name}: serving needs multi-head latent attention's latent "
+            "KV cache and a dense-prefix stack in prefill and decode, which "
+            "are not built; this model trains only")
 
 
 # --------------------------------------------------------------- prefill ---
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    _check_servable(cfg)
     kinds = cfg.layer_kinds()
     n_attn = sum(k == "attn" for k in kinds)
     cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
@@ -303,6 +318,7 @@ def lm_prefill(params, tokens, cfg: ModelConfig, max_len: int,
     Uniform-kind stacks scan over layers (cache slices emitted as scan ys) so
     the 32k-prefill dry-run HLO stays O(1) in depth.
     """
+    _check_servable(cfg)
     B, S = tokens.shape[0], tokens.shape[1]
     if extra_embeds is not None:
         S = S + extra_embeds.shape[1]
@@ -469,6 +485,7 @@ def _rglru_prefill_state(p, h, cfg):
 def lm_decode_step(params, token, cache, cfg: ModelConfig):
     """token: (B,1) int32; cache from init_cache/lm_prefill.
     Returns (logits (B,V) fp32, updated cache)."""
+    _check_servable(cfg)
     kinds = cfg.layer_kinds()
     pos = cache["pos"]
     x = jnp.take(params["embed"], token, axis=0).astype(cfg.adtype)

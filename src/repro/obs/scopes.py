@@ -18,8 +18,8 @@ from typing import Any, Dict, Optional
 
 import jax
 
-SCOPES = ("embed", "attn", "mlp", "ssm_block", "ssm_scan", "head_loss",
-          "optimizer")
+SCOPES = ("embed", "attn", "mla", "attn_core", "mlp", "moe_route",
+          "moe_experts", "ssm_block", "ssm_scan", "head_loss", "optimizer")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

@@ -153,6 +153,9 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh, n_stages: int,
     kinds = cfg.layer_kinds()
     kind = kinds[0]
     assert len(set(kinds)) == 1, "PP requires a uniform scanned stack"
+    if cfg.first_dense:
+        raise ValueError("PP stages only the scanned stack; a model with "
+                         "leading dense layers (first_dense) is not staged")
     m = n_microbatches
     if stage_tp is not None:
         assert len(stage_tp) == n_stages, \

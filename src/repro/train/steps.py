@@ -69,7 +69,7 @@ def make_loss_fn(bundle: ArchBundle, rules: ShardingRules):
         if cfg.loss_chunk and cfg.family != "encdec":
             # fuse unembed+CE over sequence chunks: the (B,S,V) logits never
             # materialize (dominant temp for 150k-256k vocabs)
-            feats, w, aux = _reg.lm_features(params, batch, cfg)
+            feats, w, aux, stats = _reg.lm_features(params, batch, cfg)
             labels = constrain(batch["labels"], P(rules.dp_axes, None))
             B, S, D = feats.shape
             c = min(cfg.loss_chunk, S)
@@ -90,7 +90,7 @@ def make_loss_fn(bundle: ArchBundle, rules: ShardingRules):
             (s_ce, s_z, cnt), _ = jax.lax.scan(
                 fn, (jnp.zeros(()), jnp.zeros(()), 0.0), (fc, lc))
             ce = s_ce / cnt + Z_COEF * (s_z / cnt)
-            return ce + AUX_COEF * aux, {"ce": ce, "aux": aux}
+            return ce + AUX_COEF * aux, {"ce": ce, "aux": aux, **stats}
         logits, aux = bundle.forward(params, batch, cfg)
         logits = constrain(logits, rules.logits_spec())
         labels = constrain(batch["labels"], P(rules.dp_axes, None))

@@ -46,7 +46,7 @@ from repro.core.plan import ParallelPlan
 from repro.ckpt import checkpoint as ckpt
 from repro.data.pipeline import DataState, SyntheticTokens
 from repro.models.registry import ArchBundle
-from repro.obs import scopes
+from repro.obs import counters, scopes
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import context, pipeline
 from repro.parallel.sharding import ShardingRules
@@ -371,7 +371,11 @@ class Trainer:
             with TraceAnnotation("trainer.sync"):
                 jax.block_until_ready(metrics["loss"])
                 dt = time.perf_counter() - t0
-                losses.append(float(metrics["loss"]))
+                host = jax.device_get(
+                    {k: metrics[k] for k in ("loss",) + counters.NAMES
+                     if k in metrics})
+                losses.append(float(host["loss"]))
+            counters.record(self.bundle.cfg, host)
             with TraceAnnotation("trainer.after"):
                 self._after_step(dt, input_s)
         self.ckpt.wait()

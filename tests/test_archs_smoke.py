@@ -111,6 +111,7 @@ def test_full_config_dims(arch):
         "phi3.5-moe-42b-a6.6b": (32, 4096, 32, 8, 6400, 32064),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "whisper-tiny": (4, 384, 6, 6, 1536, 51872),  # vocab padded for TP
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -123,3 +124,9 @@ def test_full_config_dims(arch):
         assert cfg.ssm_state == 16
     if arch == "recurrentgemma-9b":
         assert cfg.block_pattern == ("rec", "rec", "attn")
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert (cfg.n_experts, cfg.top_k, cfg.d_expert, cfg.n_shared_experts,
+                cfg.first_dense, cfg.routed_scale) == (64, 6, 1408, 2, 1,
+                                                       2.446)

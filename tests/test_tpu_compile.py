@@ -130,6 +130,36 @@ def test_scan_kernels_count_for_the_scan_scope(one_chip, monkeypatch):
     assert {table.get(n) for n in mosaic} == {"ssm_scan"}
 
 
+def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch):
+    """The held experts' Pallas grouped matmul at the Moonlight cell's
+    shapes (1 x 8192 tokens x top 6 rows, 8 experts of 2,048 x 1,408),
+    forward and backward, fits a v5e's VMEM with its tiles, and every
+    Mosaic call of it maps to the ``moe_experts`` scope."""
+    from repro.kernels import ops
+    from repro.models import moe
+    from repro.obs import scopes
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    cfg = registry.get_config("moonlight-16b-a3b")
+    M, D, F, E = 8192 * cfg.top_k, cfg.d_model, cfg.d_expert, 8
+    args = (_sds(one_chip, (M, D)), _sds(one_chip, (E, D, F)),
+            _sds(one_chip, (E, F, D)), _sds(one_chip, (E,), jnp.int32))
+
+    def loss(x, w_up, w_down, sizes):
+        with jax.named_scope("moe_experts"):
+            h = moe.grouped_matmul(x, w_up, sizes).astype(x.dtype)
+            y = moe.grouped_matmul(h, w_down, sizes)
+        return jnp.sum(jnp.sin(y))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    table = scopes.op_scopes(text)
+    mosaic = [ln.split(" = ")[0].split()[-1].lstrip("%")
+              for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(mosaic) == 6          # 2 forward, 2 x 2 backward
+    assert {table.get(n) for n in mosaic} == {"moe_experts"}
+
+
 @pytest.mark.parametrize("pp", [1, 2], ids=["data4", "pp2"])
 def test_mamba_train_step_compiles_on_four_v5e(topo, monkeypatch, pp):
     """falcon-mamba's train step on the Trainer's mesh over four described

@@ -102,6 +102,20 @@ def test_compiled_train_step_maps_to_each_layer(arch, want):
     assert _compiled_scopes(step, state, batch) == want
 
 
+def test_compiled_moonlight_step_maps_to_each_layer():
+    """Latent attention, its chunked core (forced at the smoke size), the
+    expert routing and the held experts' grouped matmuls each have their
+    scope; the shared experts and the dense layer's MLP are in ``mlp``."""
+    b = registry.get_bundle("moonlight-16b-a3b", smoke=True, attn_chunk=8)
+    rules = ShardingRules(b.cfg, tp=1, dp_axes=("data",))
+    state = steps.init_train_state(b, jax.random.PRNGKey(0))
+    batch = registry.make_batch(b.cfg, batch=2, seq=32)
+    step = steps.make_train_step(b, rules, AdamWConfig())
+    assert _compiled_scopes(step, state, batch) == {
+        "embed", "mla", "attn_core", "mlp", "moe_route", "moe_experts",
+        "head_loss", "optimizer"}
+
+
 def test_scan_kernels_map_to_the_scan_scope(monkeypatch):
     """The falcon-mamba step on the scan's kernel path (interpreted on the
     CPU): every instruction of the forward and backward kernels, remat
