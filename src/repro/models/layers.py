@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from repro.kernels import flash_attention as fa, ops
 from repro.models.config import ModelConfig
 
 
@@ -167,10 +168,14 @@ def q_chunk(cfg: ModelConfig, B: int, H: int, Sq: int, Sk: int) -> int:
 
 def attention_core(q, k, v, pos, cfg: ModelConfig, *, causal: bool = True):
     """Softmax attention of q (B,S,H,hd) over k (B,S,Hk,hd), v (B,S,Hk,dv)
-    at positions ``pos`` (S,), -> (B,S,H,dv).  Where ``q_chunk`` says so it
-    runs over chunks of queries under the scope ``attn_core``, each chunk
-    rematerialised, so that the backward pass holds one chunk's scores at
-    a time."""
+    at the consecutive positions ``pos`` (S,), -> (B,S,H,dv).  Where
+    ``q_chunk`` says the whole sequence's scores would not fit, it runs
+    under the scope ``attn_core``: on the TPU of a one-device mesh, with S
+    a multiple of the kernel's blocks, as the Pallas flash-attention pair
+    (``kernels/flash_attention.py``: score tiles in VMEM, the causal
+    blocks above the diagonal skipped); elsewhere over chunks of queries,
+    each chunk rematerialised, so that the backward pass holds one chunk's
+    scores at a time."""
     B, S, H, hd = q.shape
     chunk = q_chunk(cfg, B, H, S, k.shape[1])
     if not chunk:
@@ -187,6 +192,12 @@ def attention_core(q, k, v, pos, cfg: ModelConfig, *, causal: bool = True):
         return _sdpa(qc, k, v, mask, cfg)
 
     with jax.named_scope("attn_core"):
+        if (ops.use_pallas() and k.shape[1] == S
+                and S % fa.BLOCK_Q == 0 and S % fa.BLOCK_K == 0
+                and jax.sharding.get_abstract_mesh().size <= 1):
+            return fa.flash_attention(q, k, v, causal=causal,
+                                      window=cfg.window,
+                                      softcap=cfg.attn_logit_softcap)
         n = S // chunk
         qs = q.reshape(B, n, chunk, H, hd).transpose(1, 0, 2, 3, 4)
         os = jax.lax.map(lambda a: one(*a), (qs, jnp.arange(n)))

@@ -1,8 +1,10 @@
 """Latent attention, the sigmoid-routed expert layer that holds a share of
-the experts, the bounded attention core, and the whole Moonlight model
+the experts, the bounded attention core (chunked, or the Pallas flash pair
+interpreted), and the whole Moonlight model
 against its plain float32 reference (``benchmarks/chip/reference``), all
 at small seeded sizes on the CPU."""
 import dataclasses
+import functools
 import math
 import sys
 from pathlib import Path
@@ -12,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa, ops
 from repro.models import layers, moe, registry
 from repro.models.config import ModelConfig
 from repro.obs import counters
@@ -307,6 +310,49 @@ def test_the_chunked_core_equals_the_whole_one(window):
     assert "attn_core" in text
 
 
+@pytest.mark.parametrize("H,Hk,dqk,dv,window,softcap", [
+    (2, 2, 192, 128, None, None),
+    (4, 2, 64, 64, None, None),
+    (2, 2, 64, 32, 200, None),
+    (2, 1, 64, 64, None, 30.0),
+], ids=["causal-qk192-v128", "gqa-4-over-2", "window-200", "softcap"])
+def test_the_flash_pair_equals_the_whole_core(H, Hk, dqk, dv, window,
+                                              softcap):
+    """The Pallas pair (interpreted) with blocks of 128 over S = 512, so
+    that skipped, diagonal and full tiles all occur: its output and its
+    gradients in q, k and v against ``_sdpa`` over the masked whole
+    sequence."""
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=32,
+                      n_heads=H, n_kv_heads=Hk, d_ff=64, vocab_size=64,
+                      window=window, attn_logit_softcap=softcap,
+                      param_dtype="float32", dtype="float32")
+    S = 512
+    ks = jax.random.split(jax.random.PRNGKey(10), 3)
+    q = jax.random.normal(ks[0], (1, S, H, dqk))
+    k = jax.random.normal(ks[1], (1, S, Hk, dqk))
+    v = jax.random.normal(ks[2], (1, S, Hk, dv))
+    pos = jnp.arange(S)
+
+    def whole(q, k, v):
+        return layers._sdpa(q, k, v,
+                            layers._scores_mask(pos, pos, window, True), cfg)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  softcap=softcap, block_q=128, block_k=128,
+                                  interpret=True)
+
+    def loss(core, q, k, v):
+        o = core(q, k, v)
+        return jnp.sum(jnp.sin(o)), o
+
+    want = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(whole, q, k, v)
+    got = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(flash, q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
 def test_the_chunk_follows_the_shape_and_the_device_memory(monkeypatch):
     monkeypatch.setattr(layers, "device_memory_bytes", lambda: 16 * 2 ** 30)
     danube = registry.get_config("h2o-danube-3-4b")
@@ -323,6 +369,83 @@ def test_the_chunk_follows_the_shape_and_the_device_memory(monkeypatch):
                           4, 32, 2048, 2048) == 256
     monkeypatch.setattr(layers, "device_memory_bytes", lambda: 2 ** 16)
     assert layers.q_chunk(danube, 1, 2, 64, 64) == 4     # 4 x 512 B rows
+
+
+def test_the_core_takes_the_flash_pair_where_it_would_chunk_on_the_tpu(
+        monkeypatch):
+    """On the TPU (``ops.use_pallas``) the core runs the flash pair where it
+    would chunk, on a one-device mesh, with S a multiple of the blocks:
+    Moonlight at 1 x 8192.  Danube's 4 x 2048 and pp2 micro-batch keep the
+    whole ``_sdpa``; a sequence of 8000, or a mesh of two devices, keeps
+    the chunked core; so does the CPU."""
+    monkeypatch.setattr(layers, "device_memory_bytes", lambda: 16 * 2 ** 30)
+    took = []
+    sdpa = layers._sdpa
+
+    def spy_sdpa(q, k, v, mask, cfg):
+        took.append(("sdpa", mask.shape))
+        return sdpa(q, k, v, mask, cfg)
+
+    def spy_flash(q, k, v, **kw):
+        took.append(("flash", kw["window"]))
+        return jnp.zeros(q.shape[:3] + v.shape[3:], q.dtype)
+
+    monkeypatch.setattr(layers, "_sdpa", spy_sdpa)
+    monkeypatch.setattr(fa, "flash_attention", spy_flash)
+    danube = registry.get_config("h2o-danube-3-4b")
+    moon = registry.get_config("moonlight-16b-a3b")
+
+    def path(cfg, B, S, dqk, dv):
+        H, Hk = cfg.n_heads, cfg.n_kv_heads
+        sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        took.clear()
+        jax.eval_shape(lambda q, k, v: layers.attention_core(
+            q, k, v, jnp.arange(S), cfg), sd(B, S, H, dqk),
+            sd(B, S, Hk, dqk), sd(B, S, Hk, dv))
+        return took[:]
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    assert path(moon, 1, 8192, 192, 128) == [("flash", None)]
+    assert path(danube, 4, 2048, 120, 120) == [("sdpa", (2048, 2048))]
+    assert path(danube, 1, 2048, 120, 120) == [("sdpa", (2048, 2048))]
+    assert 8000 % fa.BLOCK_Q or 8000 % fa.BLOCK_K
+    assert path(moon, 1, 8000, 192, 128) == [("sdpa", (1000, 8000))]
+    two = jax.sharding.AbstractMesh((2,), ("data",))
+    with jax.sharding.use_abstract_mesh(two):
+        assert path(moon, 2, 8192, 192, 128) == [("sdpa", (1024, 8192))]
+    monkeypatch.setattr(ops, "use_pallas", lambda: False)
+    assert path(moon, 1, 8192, 192, 128) == [("sdpa", (1024, 8192))]
+
+
+def test_mla_through_the_flash_pair_equals_the_chunked_core(monkeypatch):
+    """Latent attention whose core chunks, on the flash pair's path
+    (interpreted, blocks of 128 over one kernel block of S): the value and
+    the gradients in every weight and the input equal the chunked core's."""
+    cfg = _smoke(attn_chunk=128)
+    S = fa.BLOCK_Q * fa.BLOCK_K // math.gcd(fa.BLOCK_Q, fa.BLOCK_K)
+    p = layers.init_mla(jax.random.PRNGKey(11), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(12), (1, S, cfg.d_model))
+
+    def loss(p, x):
+        return jnp.sum(jnp.sin(layers.mla(p, x, cfg)))
+
+    want = jax.value_and_grad(loss, (0, 1))(p, x)
+    calls = []
+    kernel = functools.partial(fa.flash_attention, block_q=128, block_k=128,
+                               interpret=True)
+
+    def flash(*a, **kw):
+        calls.append(kw)
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", flash)
+    got = jax.value_and_grad(loss, (0, 1))(p, x)
+    assert calls
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        # float32 sums in another order, over gradients of up to ~100
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=1e-4)
 
 
 def test_danubes_2048_steps_hold_no_chunked_core():

@@ -52,9 +52,13 @@ def _sds(sharding, shape, dtype=jnp.bfloat16):
 
 def _kernel_cases():
     """name -> (fn, arg shapes); h2o-danube-3-4b widths at seq 2048 (the
-    ring hop at 4096-token chunks), ssm_scan at falcon-mamba-7b's."""
+    ring hop at 4096-token chunks), the flash pair's backward at
+    moonlight-16b-a3b's core (1 x 8192, qk 192, v 128), ssm_scan at
+    falcon-mamba-7b's."""
     cfg = registry.get_config("h2o-danube-3-4b")
     ssm = registry.get_config("falcon-mamba-7b")
+    moon = registry.get_config("moonlight-16b-a3b")
+    M, Hm, mla, mv = 8192, moon.n_heads, moon.qk_hd, moon.v_hd
     B, S, D, F = 4, 2048, cfg.d_model, cfg.d_ff
     H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     C = 4096
@@ -73,6 +77,10 @@ def _kernel_cases():
             [(1, C, H, hd), (1, C, Hk, hd), (1, C, Hk, hd),
              ((1, C, H, 1), f32), ((1, C, H, 1), f32),
              ((1, C, H, hd), f32)]),
+        "attn_core_bwd": (
+            lambda q, k, v, do: jax.vjp(fa.flash_attention, q, k, v)[1](do),
+            [(1, M, Hm, mla), (1, M, Hm, mla), (1, M, Hm, mv),
+             (1, M, Hm, mv)]),
         "ssm_scan": (ss.ssm_scan,
                      [(1, S, di), (1, S, di), (1, S, ds), (1, S, ds),
                       ((di, ds), f32)]),
@@ -85,7 +93,8 @@ def _kernel_cases():
 
 
 @pytest.mark.parametrize("name", ["rmsnorm", "swiglu", "flash_attention",
-                                  "ring_step", "ssm_scan", "ssm_scan_bwd"])
+                                  "ring_step", "ssm_scan", "ssm_scan_bwd",
+                                  "attn_core_bwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_cases()[name]
     args = [_sds(one_chip, *s) if isinstance(s[0], tuple)
@@ -128,6 +137,57 @@ def test_scan_kernels_count_for_the_scan_scope(one_chip, monkeypatch):
     assert {n.rsplit(".", 1)[0] for n in mosaic} == {"ssm_scan_fwd",
                                                       "ssm_scan_bwd"}
     assert {table.get(n) for n in mosaic} == {"ssm_scan"}
+
+
+def test_attention_core_kernels_count_for_the_core_scope(one_chip,
+                                                        monkeypatch):
+    """The value and gradient of Moonlight's latent attention at 1 x 1024
+    with its core chunked (chunks of 256), compiled for a v5e: on the TPU
+    path the forward and both backward Mosaic calls of the flash pair all
+    map to ``attn_core``, and no (chunk x S) fp32 score tensor is left,
+    so no whole forward runs again for the backward; the chunked jnp core
+    holds them."""
+    import dataclasses
+    import re
+
+    from repro.kernels import ops
+    from repro.models import layers
+    from repro.obs import scopes
+    cfg = dataclasses.replace(registry.get_config("moonlight-16b-a3b"),
+                              attn_chunk=256)
+    S = 1024
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: layers.init_mla(k, cfg),
+                       jax.random.PRNGKey(0)))
+    x = _sds(one_chip, (1, S, cfg.d_model))
+
+    def loss(p, x):
+        with jax.named_scope("mla"):
+            y = layers.mla(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def compiled_text():
+        return jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            params, x).compile().as_text()
+
+    def scores(text):
+        dims = [[int(d) for d in m.split(",") if d]
+                for m in re.findall(r"f32\[([\d,]*)\]", text)]
+        return [d for d in dims if d[-2:] in ([256, S], [S, S])]
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    text = compiled_text()
+    table = scopes.op_scopes(text)
+    mosaic = [ln.split(" = ")[0].split()[-1].lstrip("%")
+              for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    assert {n.rsplit(".", 1)[0] for n in mosaic} == {
+        "attn_core_fwd", "attn_core_bwd_dq", "attn_core_bwd_dkv"}
+    assert {table.get(n) for n in mosaic} == {"attn_core"}
+    assert not scores(text)
+    monkeypatch.setattr(ops, "use_pallas", lambda: False)
+    assert scores(compiled_text())
 
 
 def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch):
